@@ -61,8 +61,7 @@ def tune(Ns=(64, 256), T: int = 16, rounds: int = 2,
     for ``write_table`` (only the active backend/mode key)."""
     mode = active_mode()
     key = f"{mode.backend}/{mode.mode}"
-    print(f"autotuning for {key} (requested={mode.requested}, "
-          f"fallback={mode.fallback})")
+    print(f"autotuning for {key}")
     lkf = get_filter("lkf")
     imm = make_imm()
     rng = np.random.default_rng(3)

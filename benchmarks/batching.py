@@ -60,7 +60,7 @@ def _run_sharded_imm(csv: List[str], S: int, T: int) -> None:
     Times the live ``ShardedBankEngine.frame`` loop (compile excluded
     by the engine's warmup), reporting fleet frames/sec — one frame =
     all S sensors serviced."""
-    from repro.compat import make_mesh
+    from repro.launch.mesh import make_mesh
     from repro.core.filters import make_imm
     from repro.core.tracker import TrackerConfig
     from repro.serving.engine import ShardedBankEngine

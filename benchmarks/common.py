@@ -12,10 +12,9 @@ from repro.execmode import active_mode
 
 
 def bench_meta() -> Dict:
-    """Top-level BENCH_*.json metadata: the resolved execution mode
-    (requested + actual), backend and jax version — so interpret-mode
-    dispatch-count wins can never be conflated with compiled-mode
-    wall-clock wins after the fact."""
+    """Top-level BENCH_*.json metadata: the execution mode, backend and
+    jax version — so interpret-mode dispatch-count wins can never be
+    conflated with compiled-mode wall-clock wins after the fact."""
     return active_mode().as_meta()
 
 
@@ -71,8 +70,6 @@ def hlo_cost(fn: Callable, *args) -> Dict[str, float]:
     ``flops`` and ``bytes`` (the ``bytes accessed`` counter), 0.0 when
     the backend doesn't report a counter."""
     ca = compiled_of(fn, *args).cost_analysis()
-    if isinstance(ca, (list, tuple)):  # jax<=0.4 returns [dict] per device
-        ca = ca[0] if ca else {}
     return dict(flops=float(ca.get("flops", 0.0)),
                 bytes=float(ca.get("bytes accessed", 0.0)))
 

@@ -121,7 +121,7 @@ def _bench_sharded(csv: List[str], out: list, S: int, T: int) -> None:
     """8-sensor IMM fleet frames/sec, fused vs einsum frame route,
     over 1/8 host devices (``ShardedBankEngine``; one frame = all S
     sensors serviced)."""
-    from repro.compat import make_mesh
+    from repro.launch.mesh import make_mesh
     from repro.serving.engine import ShardedBankEngine
 
     imm = make_imm()

@@ -53,7 +53,7 @@ from repro.core.rewrites import build_stage
 from repro.execmode import active_mode
 from repro.kernels.katana_bank.ops import (katana_bank_sequence,
                                            katana_imm_sequence)
-from repro.roofline.analysis import machine_for_backend, terms_on
+from repro.roofline.analysis import machine_for, terms_on
 from repro.roofline.hlo import op_census
 
 BENCH_JSON = pathlib.Path(__file__).resolve().parents[1] / \
@@ -73,8 +73,6 @@ def imm_useful_flops(n: int, m: int, K: int) -> float:
 
 def _cost_of(compiled) -> Dict[str, float]:
     ca = compiled.cost_analysis()
-    if isinstance(ca, (list, tuple)):  # jax<=0.4: [dict] per device
-        ca = ca[0] if ca else {}
     return dict(flops=float(ca.get("flops", 0.0)),
                 bytes=float(ca.get("bytes accessed", 0.0)))
 
@@ -136,7 +134,7 @@ def _row(csv: List[str], rows: list, name: str, fn, args, pallas: bool,
 def run(csv: List[str], Ns=(256,), T: int = 32, C: int = 256,
         M: int = 64) -> None:
     mode = active_mode()
-    machine = machine_for_backend(mode.backend)
+    machine = machine_for(jax.devices()[0].device_kind)
     rows: list = []
     lkf = get_filter("lkf")
     imm = make_imm()
@@ -211,7 +209,7 @@ def run(csv: List[str], Ns=(256,), T: int = 32, C: int = 256,
     # a natively-compiled Pallas variant is a different program than the
     # interpreter emulation — say so explicitly instead of pretending
     # the interpreted census covers it
-    if not mode.pallas_native:
+    if mode.interpret:
         for name in ("fused_scan", "imm_scan", "frame_fused"):
             csv.append(f"roofline/{name}/pallas-compiled,0,"
                        f"skip=pallas-lowering-unsupported:{mode.backend}")

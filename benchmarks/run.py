@@ -48,6 +48,9 @@ def main(argv=None) -> None:
     ap.add_argument("--smoke", action="store_true",
                     help="tiny shapes: exercise the drivers, not the perf")
     args = ap.parse_args(argv)
+    from repro.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     wanted = [w for w in args.only.split(",") if w]
     csv: List[str] = []
     failed = []

@@ -26,8 +26,9 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.core.filters import FilterModel, IMMModel
-from repro.core.rewrites import (build_batched_lanes, gaussian_loglik,
-                                 imm_mix, imm_mode_posterior, small_det,
+from repro.core.rewrites import (build_batched_lanes, einsum,
+                                 gaussian_loglik, imm_mix,
+                                 imm_mode_posterior, matmul, small_det,
                                  small_inv, stage_constants, sym_unpack,
                                  triu_pack)
 
@@ -74,18 +75,18 @@ def _predict_lanes(model: FilterModel, x: jnp.ndarray, P: jnp.ndarray,
     C = stage_constants(model, dtype)
     Qtri = C.Q[iu, ju]
     if model.is_linear:
-        x_pred = jnp.einsum("ij,kj->ki", C.F, x)
-        FP = jnp.einsum("ij,kjl->kil", C.F, P)
-        tri = jnp.einsum("ktl,tl->kt", FP[:, iu, :], C.F[ju, :]) + Qtri
+        x_pred = einsum("ij,kj->ki", C.F, x)
+        FP = einsum("ij,kjl->kil", C.F, P)
+        tri = einsum("ktl,tl->kt", FP[:, iu, :], C.F[ju, :]) + Qtri
     else:
         x_pred = model.predict_mean(x)
         Fk = model.jacobian(x)
-        FP = jnp.einsum("kij,kjl->kil", Fk, P)
-        tri = jnp.einsum("ktl,ktl->kt", FP[:, iu, :], Fk[:, ju, :]) + Qtri
+        FP = einsum("kij,kjl->kil", Fk, P)
+        tri = einsum("ktl,ktl->kt", FP[:, iu, :], Fk[:, ju, :]) + Qtri
     P_pred = sym_unpack(tri, n)
-    z_pred = jnp.einsum("mi,ki->km", C.H, x_pred)
-    PHt = jnp.einsum("kij,mj->kim", P_pred, C.H)
-    S = jnp.einsum("mi,kij,nj->kmn", C.H, P_pred, C.H) + C.R
+    z_pred = einsum("mi,ki->km", C.H, x_pred)
+    PHt = einsum("kij,mj->kim", P_pred, C.H)
+    S = einsum("mi,kij,nj->kmn", C.H, P_pred, C.H) + C.R
     Sinv = small_inv(S, model.m)
     return x_pred, P_pred, z_pred, S, Sinv, PHt
 
@@ -100,12 +101,12 @@ def _kalman_update_lanes(model: FilterModel, x_pred, P_pred, zk, PHt, Sinv,
     n = model.n
     iu, ju, _ = triu_pack(n)
     C = stage_constants(model, dtype)
-    y = zk + jnp.einsum("mi,ki->km", C.H_neg, x_pred)
-    K = jnp.einsum("kim,kmn->kin", PHt, Sinv)
-    x_new = x_pred + jnp.einsum("kin,kn->ki", K, y)
-    HnP = jnp.einsum("mi,kij->kmj", C.H_neg, P_pred)
+    y = zk + einsum("mi,ki->km", C.H_neg, x_pred)
+    K = einsum("kim,kmn->kin", PHt, Sinv)
+    x_new = x_pred + einsum("kin,kn->ki", K, y)
+    HnP = einsum("mi,kij->kmj", C.H_neg, P_pred)
     tri = (P_pred[:, iu, ju]
-           + jnp.einsum("ktm,kmt->kt", K[:, iu, :], HnP[:, :, ju]))
+           + einsum("ktm,kmt->kt", K[:, iu, :], HnP[:, :, ju]))
     return x_new, sym_unpack(tri, n)
 
 
@@ -149,9 +150,9 @@ def update_bank(model: FilterModel, bank: BankState, z: jnp.ndarray,
     zk = z[jnp.clip(assoc, 0, z.shape[0] - 1)]  # (Cap, m), garbage where -1
     x_pred, P_pred = bank.x, bank.P
     if PHt is None:
-        PHt = jnp.einsum("kij,mj->kim", P_pred, C.H)
+        PHt = einsum("kij,mj->kim", P_pred, C.H)
     if Sinv is None:
-        S = jnp.einsum("mi,kij,nj->kmn", C.H, P_pred, C.H) + C.R
+        S = einsum("mi,kij,nj->kmn", C.H, P_pred, C.H) + C.R
         Sinv = small_inv(S, model.m)
     x_new, P_new = _kalman_update_lanes(model, x_pred, P_pred, zk, PHt, Sinv,
                                         dtype)
@@ -198,10 +199,10 @@ def _spawn_init_state(model: FilterModel, take: jnp.ndarray, z: jnp.ndarray,
     """Measurement-seeded initial state per claiming slot: z mapped
     through Hᵀ (exact for position-selector H), the unobserved state
     components at the model defaults."""
-    zsel = jnp.einsum("sm,mq->sq", take.astype(z.dtype), z)  # (Cap, m)
+    zsel = einsum("sm,mq->sq", take.astype(z.dtype), z)  # (Cap, m)
     Ht = jnp.asarray(model.H.T, dtype)
-    return jnp.einsum("nm,sm->sn", Ht, zsel) + jnp.asarray(
-        model.x0, dtype) * (1.0 - jnp.einsum("nm,m->n", Ht,
+    return einsum("nm,sm->sn", Ht, zsel) + jnp.asarray(
+        model.x0, dtype) * (1.0 - einsum("nm,m->n", Ht,
                                              jnp.ones((model.m,), dtype)))
 
 
@@ -386,20 +387,20 @@ def update_imm_bank(imm: IMMModel, bank: IMMBankState, z: jnp.ndarray,
     consts = ([stage_constants(model, dtype) for model in imm.models]
               if z_pred is None or PHt is None or S is None else None)
     if z_pred is None:
-        z_pred = jnp.stack([jnp.einsum("mi,ki->km", Ck.H, bank.x[k])
+        z_pred = jnp.stack([einsum("mi,ki->km", Ck.H, bank.x[k])
                             for k, Ck in enumerate(consts)])
     if PHt is None:
-        PHt = jnp.stack([jnp.einsum("kij,mj->kim", bank.P[k], Ck.H)
+        PHt = jnp.stack([einsum("kij,mj->kim", bank.P[k], Ck.H)
                          for k, Ck in enumerate(consts)])
     if S is None:
         # S feeds the likelihood normalizer even when Sinv is given
-        S = jnp.stack([jnp.einsum("mi,kij,nj->kmn", Ck.H, bank.P[k], Ck.H)
+        S = jnp.stack([einsum("mi,kij,nj->kmn", Ck.H, bank.P[k], Ck.H)
                        + Ck.R
                        for k, Ck in enumerate(consts)])
     if Sinv is None:
         Sinv = small_inv(S, m)
     if cbar is None:
-        cbar = bank.mu @ jnp.asarray(imm.trans, dtype)
+        cbar = matmul(bank.mu, jnp.asarray(imm.trans, dtype))
     has_z = assoc >= 0
     zk = z[jnp.clip(assoc, 0, z.shape[0] - 1)]  # (C, m), garbage where -1
     x_new, P_new, loglik = [], [], []

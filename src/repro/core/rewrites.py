@@ -70,6 +70,21 @@ import numpy as np
 
 from repro.core.filters import FilterModel, IMMModel, as_imm
 
+# The tracker's XLA contractions run at full f32 precision on every
+# backend: a TPU's default for an f32 dot is one bf16 pass (about three
+# significant digits), which would round the einsum path's states and
+# every spawned track's position. The CPU computes f32 either way.
+_F32 = jax.lax.Precision.HIGHEST
+
+
+def einsum(subscripts, *operands):
+    return jnp.einsum(subscripts, *operands, precision=_F32)
+
+
+def matmul(a, b):
+    return jnp.matmul(a, b, precision=_F32)
+
+
 STAGES = ("baseline", "opt1", "opt2", "batched_blockdiag", "batched_lanes",
           "fused_scan", "imm_bank", "imm_scan")
 
@@ -123,13 +138,14 @@ def inv4(M):
     C = M[..., 2:, :2]
     D = M[..., 2:, 2:]
     Di = inv2(D)
-    BDi = B @ Di
-    S = A - BDi @ C  # Schur complement
+    BDi = matmul(B, Di)
+    S = A - matmul(BDi, C)  # Schur complement
     Si = inv2(S)
-    SiBDi = Si @ BDi
-    DiC = Di @ C
+    SiBDi = matmul(Si, BDi)
+    DiC = matmul(Di, C)
     top = jnp.concatenate([Si, -SiBDi], axis=-1)
-    bot = jnp.concatenate([-DiC @ Si, Di + DiC @ SiBDi], axis=-1)
+    bot = jnp.concatenate([-matmul(DiC, Si), Di + matmul(DiC, SiBDi)],
+                          axis=-1)
     return jnp.concatenate([top, bot], axis=-2)
 
 
@@ -188,7 +204,8 @@ def small_det(M, dim: int):
                 + m[0][2] * (m[1][0] * m[2][1] - m[1][1] * m[2][0]))
     if dim == 4:
         D = M[..., 2:, 2:]
-        S = M[..., :2, :2] - M[..., :2, 2:] @ inv2(D) @ M[..., 2:, :2]
+        S = M[..., :2, :2] - matmul(matmul(M[..., :2, 2:], inv2(D)),
+                                    M[..., 2:, :2])
         return small_det(D, 2) * small_det(S, 2)
     return jnp.linalg.det(M)
 
@@ -214,7 +231,7 @@ def imm_mix(x, P, mu, Pi):
     probability. The spread term (x_i - x_mix_j)(x_i - x_mix_j)^T keeps
     P_mix consistent (and PSD) under mode disagreement.
     """
-    cbar = mu @ Pi                                           # (B, K)
+    cbar = matmul(mu, Pi)                                    # (B, K)
     # cbar_j = 0 (a mode the chain cannot reach, e.g. an identity
     # transition with mu_j = 0) would divide 0/0 here; clamping the
     # denominator keeps w finite and exactly 0 for that column, and the
@@ -222,10 +239,10 @@ def imm_mix(x, P, mu, Pi):
     # imm_mode_posterior — no NaN ever enters the track state.
     cbar_safe = jnp.maximum(cbar, jnp.finfo(cbar.dtype).tiny)
     w = mu[:, :, None] * Pi[None, :, :] / cbar_safe[:, None, :]  # (B, i, j)
-    x_mix = jnp.einsum("bij,ibd->jbd", w, x)
+    x_mix = einsum("bij,ibd->jbd", w, x)
     dx = x[:, None] - x_mix[None, :]                         # (i, j, B, n)
-    P_mix = (jnp.einsum("bij,ibuv->jbuv", w, P)
-             + jnp.einsum("bij,ijbu,ijbv->jbuv", w, dx, dx))
+    P_mix = (einsum("bij,ibuv->jbuv", w, P)
+             + einsum("bij,ijbu,ijbv->jbuv", w, dx, dx))
     return x_mix, P_mix, cbar
 
 
@@ -247,10 +264,10 @@ def imm_combine(x, P, mu):
 
     x: (K, B, n); P: (K, B, n, n); mu: (B, K) -> (x_c (B, n),
     P_c (B, n, n))."""
-    x_c = jnp.einsum("bk,kbd->bd", mu, x)
+    x_c = einsum("bk,kbd->bd", mu, x)
     dx = x - x_c[None]                                       # (K, B, n)
-    P_c = (jnp.einsum("bk,kbuv->buv", mu, P)
-           + jnp.einsum("bk,kbu,kbv->buv", mu, dx, dx))
+    P_c = (einsum("bk,kbuv->buv", mu, P)
+           + einsum("bk,kbu,kbv->buv", mu, dx, dx))
     return x_c, P_c
 
 
@@ -259,7 +276,7 @@ def gaussian_loglik(y, Sinv, logdetS, m: int):
     cofactor inverse Sinv (..., m, m) and log det S (...). No inversion
     happens here — the whole point is to reuse the S^{-1} the Kalman
     gain already paid for (predict_bank / the kernel's emitted Sinv)."""
-    d = jnp.einsum("...u,...uv,...v->...", y, Sinv, y)
+    d = einsum("...u,...uv,...v->...", y, Sinv, y)
     return -0.5 * (d + logdetS + m * _LOG_2PI)
 
 

@@ -35,7 +35,7 @@ import numpy as np
 from repro.core import bank as bank_lib
 from repro.core.bank import BankState, IMMBankState
 from repro.core.filters import FilterModel, IMMModel
-from repro.core.rewrites import imm_combine
+from repro.core.rewrites import einsum, imm_combine
 
 # 99% chi-square quantiles by dof (m <= 6 covers the paper's workloads)
 CHI2_99 = {1: 6.63, 2: 9.21, 3: 11.34, 4: 13.28, 5: 15.09, 6: 16.81}
@@ -55,12 +55,6 @@ class TrackerConfig:
     # equivalence oracle (and the automatic fallback for models the
     # kernel can't serve: non-selector H, nonlinear IMM members).
     fused_frame: bool = True
-    # Execution mode for the kernel dispatches: None defers to the
-    # KATANA_MODE env var ("auto"/"interpret"/"compiled"); an explicit
-    # value here pins this tracker. A "compiled" request on a backend
-    # that can't lower Pallas falls back to the interpreter loudly
-    # (repro.execmode.ExecModeFallbackWarning) — never silently.
-    mode: Optional[str] = None
     # Degradation knobs (the streaming front end's service ladder,
     # repro.serving.stream): gate_scale multiplies the chi-square gate —
     # a widened gate keeps tracks associated under degraded measurement
@@ -72,12 +66,6 @@ class TrackerConfig:
     # einsums. Serving front ends rely on this to survive corrupt
     # sensor payloads without a bank reset.
     nan_guard: bool = True
-
-    def exec_mode(self):
-        """The resolved ``repro.execmode.ExecMode`` for this tracker."""
-        from repro.execmode import resolve_mode
-
-        return resolve_mode(self.mode)
 
 
 class FrameResult(NamedTuple):
@@ -96,7 +84,7 @@ def mahalanobis_cost(z_pred: jnp.ndarray, Sinv: jnp.ndarray,
     Mahalanobis. Takes the inverse ``predict_bank`` already produced —
     gating never re-inverts the innovation covariance."""
     y = z[None, :, :] - z_pred[:, None, :]        # (C, M, m)
-    return jnp.einsum("cMm,cmn,cMn->cM", y, Sinv, y)
+    return einsum("cMm,cmn,cMn->cM", y, Sinv, y)
 
 
 def greedy_assign(cost: jnp.ndarray, valid: jnp.ndarray, gate: float,
@@ -177,8 +165,7 @@ def frame_step(model: FilterModel, cfg: TrackerConfig, bank: BankState,
 
         x2, P2, assoc = katana_frame(model, bank.x, bank.P, zt, z_valid,
                                      bank.active, gate=float(gate),
-                                     rounds=rounds,
-                                     interpret=cfg.exec_mode().interpret)
+                                     rounds=rounds)
         hits, misses, age = bank_lib.lifecycle_counters(bank, assoc)
         bank_u = bank._replace(x=x2, P=P2, hits=hits, misses=misses,
                                age=age)
@@ -228,8 +215,7 @@ def imm_frame_step(imm: IMMModel, cfg: TrackerConfig, bank: IMMBankState,
 
         x2, P2, mu2, x_c, assoc = katana_imm_frame(
             imm, bank.x, bank.P, bank.mu, zt, z_valid, bank.active,
-            gate=float(gate), rounds=rounds,
-            interpret=cfg.exec_mode().interpret)
+            gate=float(gate), rounds=rounds)
         hits, misses, age = bank_lib.lifecycle_counters(bank, assoc)
         bank_u = bank._replace(x=x2, P=P2, mu=mu2, hits=hits,
                                misses=misses, age=age)

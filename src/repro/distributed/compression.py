@@ -20,8 +20,6 @@ from typing import Any, Tuple
 import jax
 import jax.numpy as jnp
 
-from repro import compat
-
 
 def _quantize(g: jnp.ndarray) -> Tuple[jnp.ndarray, jnp.ndarray]:
     g32 = g.astype(jnp.float32)
@@ -62,7 +60,7 @@ def compressed_psum(x: jnp.ndarray, axis: str) -> jnp.ndarray:
     shared via one pmax). This is the real compressed collective used
     at the pod boundary; ``ef_compress`` supplies the error feedback.
     """
-    P = compat.axis_size(axis)
+    P = jax.lax.axis_size(axis)
     smax = jax.lax.pmax(jnp.max(jnp.abs(x.astype(jnp.float32))), axis)
     smax = jnp.maximum(smax, 1e-12) / 127.0
     q = jnp.clip(jnp.round(x.astype(jnp.float32) / smax), -127, 127

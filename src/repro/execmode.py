@@ -1,54 +1,34 @@
 """Execution-mode resolution for the katana Pallas kernels.
 
-Every kernel op used to default to ``interpret=True`` — correct on this
-CPU container, but it let "interpret" leak into benchmark numbers
-without being recorded, so dispatch-count wins measured through the
-Pallas interpreter were indistinguishable from compiled-kernel wins.
-This module is the single place that decision is made:
+The backend decides how a kernel runs, with no probe and no fallback:
 
-  * ``KATANA_MODE`` env (``auto`` / ``interpret`` / ``compiled``) or an
-    explicit per-call / per-``TrackerConfig`` request selects the mode;
-  * ``pallas_lowering_supported()`` probes (once, cached) whether the
-    active jax backend can actually lower a ``pallas_call`` with
-    ``interpret=False`` — CPU backends up to current jax cannot;
-  * a ``compiled`` request on a backend that can't lower falls back to
-    the interpreter LOUDLY: a ``ExecModeFallbackWarning`` at resolve
-    time plus a non-None ``ExecMode.fallback`` reason that benchmark
-    rows and the CI compiled-mode job assert on. Interpreted execution
-    can never silently masquerade as compiled.
+  * on a TPU backend every kernel runs compiled (Mosaic). A kernel the
+    TPU compiler refuses raises; nothing turns it into the interpreter,
+    and asking for the interpreter there is an error;
+  * on any other backend (the CPU the test suite runs on) Pallas TPU
+    kernels cannot compile, so they run through the Pallas interpreter
+    — slow, but the same op stream. ``interpret=False`` there lowers a
+    kernel for a described TPU (the compile rehearsals); run on the
+    CPU, it raises.
 
-The resolved ``ExecMode`` also names the backend and jax version so
-every BENCH_*.json row can record how its code actually executed:
-``lowering="pallas-interpret"`` (kernel through the interpreter),
-``"pallas"`` (natively compiled kernel), or ``"xla"`` (the XLA-native
-einsum/lanes formulation — real compiled code on every backend,
-including CPU).
+Nothing else chooses the mode: no env var, no config field. The
+resolved ``ExecMode`` names the backend and jax version so every
+BENCH_*.json row records how its code actually executed:
+``lowering="pallas"`` (natively compiled kernel),
+``"pallas-interpret"`` (kernel through the interpreter), or ``"xla"``
+(the XLA-native einsum/lanes formulation — compiled code on every
+backend, including CPU).
 """
 from __future__ import annotations
 
-import functools
-import os
-import warnings
 from dataclasses import dataclass
 from typing import Optional
-
-ENV_VAR = "KATANA_MODE"
-MODES = ("auto", "interpret", "compiled")
-
-
-class ExecModeFallbackWarning(UserWarning):
-    """A requested ``compiled`` execution is running interpreted because
-    the backend cannot lower Pallas — loud by design."""
 
 
 @dataclass(frozen=True)
 class ExecMode:
-    requested: str        # what the caller/env asked for
-    mode: str             # what actually runs: "interpret" | "compiled"
+    mode: str             # what runs: "interpret" | "compiled"
     backend: str          # jax.default_backend()
-    pallas_native: bool   # backend can lower pallas_call(interpret=False)
-    fallback: Optional[str]  # non-None iff compiled was requested but
-    #                          the kernels run interpreted
     jax_version: str
 
     @property
@@ -67,89 +47,40 @@ class ExecMode:
     def row_mode(self, pallas: bool = True) -> str:
         """The honest per-BENCH-row mode label: XLA-native paths are
         compiled code on every backend; Pallas paths are compiled only
-        when the kernel itself lowered natively."""
+        on a TPU backend."""
         return "interpret" if self.lowering(pallas) == "pallas-interpret" \
             else "compiled"
 
     def as_meta(self) -> dict:
         """Top-of-file metadata for BENCH_*.json."""
-        return dict(requested=self.requested, mode=self.mode,
-                    backend=self.backend, pallas_native=self.pallas_native,
-                    fallback=self.fallback, jax=self.jax_version)
+        return dict(mode=self.mode, backend=self.backend,
+                    jax=self.jax_version)
 
 
-@functools.lru_cache(maxsize=None)
-def pallas_lowering_supported(backend: Optional[str] = None) -> bool:
-    """Probe (once per backend) whether ``pallas_call(interpret=False)``
-    lowers on this jax backend. CPU raises ``Only interpret mode is
-    supported on CPU backend`` up to current jax; TPU/GPU lower."""
-    import jax
-    from jax.experimental import pallas as pl
-
-    def _kernel(x_ref, o_ref):
-        o_ref[...] = x_ref[...] + 1.0
-
-    try:
-        import jax.numpy as jnp
-        x = jnp.zeros((8, 128), jnp.float32)
-        jax.jit(lambda x: pl.pallas_call(
-            _kernel, out_shape=jax.ShapeDtypeStruct(x.shape, x.dtype),
-            interpret=False)(x)).lower(x)
-        return True
-    except Exception:  # noqa: BLE001 — any lowering failure means "no"
-        return False
-
-
-@functools.lru_cache(maxsize=None)
-def _resolve(requested: str, backend: str, jax_version: str) -> ExecMode:
-    native = pallas_lowering_supported(backend)
-    fallback = None
-    if requested == "auto":
-        mode = "compiled" if native else "interpret"
-    elif requested == "interpret":
-        mode = "interpret"
-    else:  # compiled
-        if native:
-            mode = "compiled"
-        else:
-            mode = "interpret"
-            fallback = f"pallas-lowering-unsupported:{backend}"
-            warnings.warn(
-                f"KATANA_MODE=compiled requested but the {backend!r} jax "
-                f"backend cannot lower Pallas kernels — kernel dispatches "
-                f"fall back to the interpreter (XLA-native einsum/lanes "
-                f"paths still run compiled). Benchmark rows record this "
-                f"as fallback={fallback!r}.",
-                ExecModeFallbackWarning, stacklevel=3)
-    return ExecMode(requested=requested, mode=mode, backend=backend,
-                    pallas_native=native, fallback=fallback,
-                    jax_version=jax_version)
-
-
-def resolve_mode(requested: Optional[str] = None) -> ExecMode:
-    """Resolve the execution mode: explicit ``requested`` wins, else the
-    ``KATANA_MODE`` env var, else ``auto`` (compiled where the backend
-    can lower Pallas, interpret elsewhere)."""
-    import jax
-
-    if requested is None:
-        requested = os.environ.get(ENV_VAR, "auto").strip().lower() or "auto"
-    if requested not in MODES:
-        raise ValueError(
-            f"{ENV_VAR}={requested!r}: expected one of {MODES}")
-    return _resolve(requested, jax.default_backend(), jax.__version__)
+def backend_mode(backend: str) -> str:
+    """The one mode a backend runs Pallas kernels in."""
+    return "compiled" if backend == "tpu" else "interpret"
 
 
 def active_mode() -> ExecMode:
-    """The environment-resolved mode (what ops use when no explicit
-    ``interpret=``/``mode=`` is passed)."""
-    return resolve_mode(None)
+    """The mode of the default backend (what ops use when no explicit
+    ``interpret=`` is passed)."""
+    import jax
+
+    backend = jax.default_backend()
+    return ExecMode(mode=backend_mode(backend), backend=backend,
+                    jax_version=jax.__version__)
 
 
-def resolve_interpret(interpret: Optional[bool] = None,
-                      mode: Optional[str] = None) -> bool:
-    """The ops-level shim: an explicit ``interpret=`` always wins
-    (tests pin the interpreter); otherwise the resolved mode decides."""
-    if interpret is not None:
-        return bool(interpret)
-    return resolve_mode(mode).interpret
+def resolve_interpret(interpret: Optional[bool] = None) -> bool:
+    """The ops-level shim: an explicit ``interpret=`` wins (tests pin
+    the interpreter on CPU; compile rehearsals pin ``False`` to lower
+    for a described TPU), otherwise the backend decides. The
+    interpreter never runs on a TPU backend."""
+    mode = active_mode()
+    if interpret is None:
+        return mode.interpret
+    if interpret and mode.mode == "compiled":
+        raise ValueError("the Pallas interpreter is for CPU only: on "
+                         "a TPU backend the kernels run compiled")
+    return bool(interpret)

@@ -39,14 +39,15 @@ from repro.execmode import ExecMode, active_mode
 TUNED_PATH = pathlib.Path(__file__).with_name("tuned.json")
 TABLE_FORMAT = 1
 
-# static fallbacks when the table has no matching entry (the historical
-# hard-coded defaults, unchanged)
+# static fallbacks when the table has no matching entry
 STATIC_DEFAULTS = {
     "katana_bank": dict(lane_tile=256),
-    "katana_bank_sequence": dict(lane_tile=256, time_chunk=4096),
+    # time_chunk 0: the chunk whose whole-T blocks fit the scoped VMEM
+    # (kernel.scan_time_chunk — 384 frames for cv-6/ctra-8 at 256 lanes)
+    "katana_bank_sequence": dict(lane_tile=256, time_chunk=0),
     "katana_bank_imm": dict(lane_tile=256),
     "imm_bank_sequence": dict(lane_tile=256),
-    # lane_tile 0 keeps the LANE_TILE//K split heuristic in ops
+    # lane_tile 0 keeps ops' LANE_TILE//K track tile, at least 128
     "katana_imm_sequence": dict(lane_tile=0, time_chunk=64),
 }
 

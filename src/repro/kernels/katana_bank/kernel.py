@@ -54,12 +54,12 @@ Six kernel shapes share the same emitted step math:
         per-model predict+updates, the mode posterior AND the
         moment-matched combination all inside one fori_loop over T —
         a whole K-hypothesis IMM stream is ONE dispatch, with x/P/mu
-        VMEM-resident across frames. Each program's block flattens to
-        tile-local model-major lanes (the K hypotheses of a track at a
-        fixed stride), so mixing reaches across models with static
-        slices; shared F/Q/R entries and the (K, K) Markov transition
-        matrix fold to trace-time Python floats, model-varying entries
-        to loop-invariant lane vectors.
+        VMEM-resident across frames. Every state entry of a program's
+        block is a (K, tt) model-major slab (row k = model k's
+        hypotheses of the tile's tracks), so mixing reaches across
+        models with static row reads; shared F/Q/R entries and the
+        (K, K) Markov transition matrix fold to trace-time Python
+        floats, model-varying entries to loop-invariant slabs.
   ``make_frame_kernel`` / ``make_imm_frame_kernel``  the LIVE serving
         frame: predict, innovation + cofactor S^{-1}, the gated
         Mahalanobis cost tile, the greedy assignment (wave-scheduled
@@ -76,8 +76,9 @@ Layout: struct-of-arrays, lanes-minor —
   x (n, N), P (n, n, N), z (m, N) / zs (T, m, N); grid tiles N by
   ``lane_tile``. For the per-frame IMM kernel the lane axis is the
   flattened (model, track) product, model-major across the whole bank;
-  the IMM scan kernel carries the model index as a leading block axis
-  and flattens it model-major WITHIN each program's tile.
+  the IMM scan and IMM frame kernels carry the model index as a leading
+  block axis and keep it there in-kernel: (K, tt) slabs, never a rank-1
+  (K·tt,) flattening (a shape cast Mosaic cannot lay out on TPU).
 """
 from __future__ import annotations
 
@@ -92,6 +93,30 @@ from jax.experimental import pallas as pl
 from repro.core.filters import FilterModel
 
 LANE_TILE = 256  # filters per program: 2 f32 lane-groups
+
+# Scoped VMEM a Mosaic kernel may allocate by default (16 MiB on TPU
+# v5e). The scan kernels' whole-chunk blocks are sized against it.
+SCOPED_VMEM_BYTES = 16 * 2**20
+
+
+def _pad8(d: int) -> int:
+    return -(-d // 8) * 8
+
+
+def scan_time_chunk(n: int, m: int, lane_tile: int) -> int:
+    """Frames per scan dispatch whose whole-chunk blocks fit in VMEM.
+
+    A scan program holds its (T, m, lane_tile) measurement block and
+    its (T, n, lane_tile) output block in VMEM as f32, each double-
+    buffered, the two minor dims tiled (8, 128):
+        T · (pad8(m) + pad8(n)) · lane_tile · 4 B · 2 buffers.
+    Three quarters of the scoped VMEM go to these blocks; the rest
+    holds the x/P state blocks and the compiler's scratch. LKF cv-6
+    and EKF ctra-8 at lane_tile 256 stream 16 rows · 256 · 8 B = 32 KiB
+    per frame, so 384 frames (12 MiB) per dispatch; cv-9 streams 24
+    rows, 256 frames."""
+    per_frame = (_pad8(m) + _pad8(n)) * lane_tile * 4 * 2
+    return max(1, SCOPED_VMEM_BYTES * 3 // 4 // per_frame)
 
 
 def _selector_rows(H: np.ndarray) -> Optional[List[int]]:
@@ -508,29 +533,28 @@ def make_imm_step_fn(models, symmetrize: bool = True):
 _F32_TINY = float(np.finfo(np.float32).tiny)
 
 
-def _emit_imm_mix(xv, P, mu, Pi, n, K, tt, sym):
-    """IMM interaction (mixing) on model-major flattened lanes: every
-    state entry xv[d] / P[r][c] and mu is one (K·tt,) vector whose K
-    hypotheses of a track sit a fixed stride ``tt`` apart, so model i's
-    slab is the STATIC slice [i·tt, (i+1)·tt) — the K x K interaction
-    unrolls into slice / scaled-add ops on (tt,) vectors and one concat
-    per mixed entry, keeping the whole frame's op stream 1-D elementwise
-    (the shape class this backend executes best: higher-rank
-    broadcast/reduce and batched-einsum formulations of the same
-    contraction measured 3-6x slower per frame). ``Pi`` is the (K, K)
-    transition matrix as trace-time Python floats: zeros prune whole
-    terms and ones elide multiplies, §IV-C constant folding applied to
-    the Markov chain.
+def _emit_imm_mix(xv, P, mu, Pi, n, K, sym):
+    """IMM interaction (mixing) on model-major (K, tt) slabs: every
+    state entry xv[d] / P[r][c] is one (K, tt) value whose row i holds
+    model i's hypotheses of the tile's tt tracks, so model i's slab is
+    the STATIC row ``v[i]``; mu is the K (tt,) mode-probability rows.
+    The K x K interaction unrolls into row / scaled-add ops on (tt,)
+    vectors and one row stack per mixed entry, keeping the whole
+    frame's op stream elementwise. (A rank-1 (K·tt,) flattening of the
+    same slabs is a shape cast Mosaic cannot lay out on TPU.) ``Pi`` is
+    the (K, K) transition matrix as
+    trace-time Python floats: zeros prune whole terms and ones elide
+    multiplies, §IV-C constant folding applied to the Markov chain.
 
     Returns (x_mix, P_mix, cbar_parts) mirroring ``rewrites.imm_mix``:
-    x_mix / P_mix are (K·tt,) vectors, cbar_parts the K per-mode (tt,)
+    x_mix / P_mix are (K, tt) slabs, cbar_parts the K per-mode (tt,)
     predicted probabilities. The same tiny-clamped denominator keeps an
     unreachable mode's 0/0 finite, and the spread term
     (x_i - x_mix_j)(·)ᵀ keeps P_mix consistent. Under ``sym`` only the
     upper triangle of P_mix is computed, mirrors aliased.
     """
-    mu_i = [mu[i * tt:(i + 1) * tt] for i in range(K)]
-    x_i = [[xv[d][i * tt:(i + 1) * tt] for i in range(K)] for d in range(n)]
+    mu_i = [mu[i] for i in range(K)]
+    x_i = [[xv[d][i] for i in range(K)] for d in range(n)]
     cbar_parts, w = [], []
     for j in range(K):
         cj = _emit_dot([Pi[i][j] for i in range(K)], mu_i, K)
@@ -551,33 +575,32 @@ def _emit_imm_mix(xv, P, mu, Pi, n, K, tt, sym):
     xt = [[0.0 if i == 0 else x_i[d][i] - x_i[d][0] for i in range(K)]
           for d in range(n)]
     mt = [[_emit_dot(w[j], xt[d], K) for j in range(K)] for d in range(n)]
-    x_mix = [jnp.concatenate([_bc(mt[d][j] + x_i[d][0], mu_i[0])
-                              for j in range(K)]) for d in range(n)]
+    x_mix = [jnp.stack([_bc(mt[d][j] + x_i[d][0], mu_i[0])
+                        for j in range(K)]) for d in range(n)]
     P_mix = [[None] * n for _ in range(n)]
     for r in range(n):
         for c in (range(r, n) if sym else range(n)):
-            A_i = [P[r][c][i * tt:(i + 1) * tt] if _is_zero(xt[r][i])
-                   or _is_zero(xt[c][i])
-                   else P[r][c][i * tt:(i + 1) * tt] + xt[r][i] * xt[c][i]
+            A_i = [P[r][c][i] if _is_zero(xt[r][i]) or _is_zero(xt[c][i])
+                   else P[r][c][i] + xt[r][i] * xt[c][i]
                    for i in range(K)]
             # _bc: a mode with an all-zero transition column folds its
             # whole slab to the float 0.0 (w[j] is all-zero), which
-            # jnp.concatenate cannot take
+            # jnp.stack cannot take
             parts = [_bc(_emit_dot(w[j], A_i, K) - mt[r][j] * mt[c][j],
                          mu_i[0]) for j in range(K)]
-            P_mix[r][c] = jnp.concatenate(parts)
+            P_mix[r][c] = jnp.stack(parts)
             if sym:
                 P_mix[c][r] = P_mix[r][c]
     return x_mix, P_mix, cbar_parts
 
 
-def _emit_mode_posterior(cbar_parts, ll, K, tt):
-    """mu'_k ∝ cbar_k exp(ll_k - max ll), per-mode slabs of the (K·tt,)
-    log-likelihood vector — the shift-stable mode-probability update
+def _emit_mode_posterior(cbar_parts, ll, K):
+    """mu'_k ∝ cbar_k exp(ll_k - max ll), per-mode rows of the (K, tt)
+    log-likelihood slab — the shift-stable mode-probability update
     (``rewrites.imm_mode_posterior`` emitted in-kernel; the max
     guarantees at least one finite weight). Returns the K (tt,)
-    posterior slabs."""
-    ll_k = [ll[k * tt:(k + 1) * tt] for k in range(K)]
+    posterior rows."""
+    ll_k = [ll[k] for k in range(K)]
     mx = ll_k[0]
     for k in range(1, K):
         mx = jnp.maximum(mx, ll_k[k])
@@ -612,6 +635,27 @@ def _emit_cost_tile(z_pred, Sinv, z_rows, m):
         t = y[r] * Sy
         d = t if d is None else d + t
     return d
+
+
+def _row(v, k):
+    """Model k's (tt,) row of a (K, tt) slab entry (python floats pass
+    through)."""
+    return v if isinstance(v, (int, float)) else v[k]
+
+
+def _emit_model_cost_tile(xp, Sinv, z_rows, obs, m, k):
+    """Model k's (M, tt) Mahalanobis cost tile from the (K, tt) slabs of
+    the predicted state and S^{-1}."""
+    return _emit_cost_tile([_row(xp[obs[r]], k) for r in range(m)],
+                           [[_row(v, k) for v in row] for row in Sinv],
+                           z_rows, m)
+
+
+def _imm_table_slabs(V, tt, dtype):
+    """The (E, K) varying-constant values of ``plan_imm_tables`` as E
+    (K, tt) slabs: row k of slab e holds model k's value of entry e."""
+    return [jnp.stack([jnp.full((tt,), float(v), dtype) for v in row])
+            for row in V]
 
 
 _BIG = float(np.finfo(np.float32).max)
@@ -747,12 +791,12 @@ def make_imm_frame_kernel(models, trans, gate: float, rounds: int,
     (``tracker.imm_frame_step``).
 
     Layout matches ``make_imm_scan_kernel``: blocks arrive as
-    x (K, n, C), P (K, n, n, C), mu (K, C) and flatten in-kernel to
-    model-major (K·C,) lanes, so the mixing reaches across models with
-    static slices and the K predict+updates emit ONE op stream
-    (shared F/Q/R entries fold to trace-time floats via
-    ``plan_imm_tables``; varying entries become loop-invariant lane
-    vectors). The gate weighs each model's Mahalanobis distance by the
+    x (K, n, C), P (K, n, n, C), mu (K, C) and every state entry stays
+    a (K, C) model-major slab in-kernel, so the mixing reaches across
+    models with static row reads and the K predict+updates emit ONE op
+    stream (shared F/Q/R entries fold to trace-time floats via
+    ``plan_imm_tables``; varying entries become loop-invariant slabs).
+    The gate weighs each model's Mahalanobis distance by the
     Markov-predicted cbar — exactly ``tracker.imm_frame_step``'s
     mode-probability-weighted gate. Coasting lanes (no measurement)
     keep the predicted x̂/P̂ and the Markov-predicted cbar, matching
@@ -790,20 +834,17 @@ def make_imm_frame_kernel(models, trans, gate: float, rounds: int,
     def kernel(x_ref, P_ref, mu_ref, z_ref, zv_ref, act_ref,
                x_out, P_out, mu_out, xc_out, a_out):
         tt = x_ref.shape[-1]
-        L = K * tt
-        mu = mu_ref[:, :].reshape(L)
-        proto = mu
-        xv = [x_ref[:, i, :].reshape(L) for i in range(n)]
-        P = [[P_ref[:, i, j, :].reshape(L) for j in range(n)]
-             for i in range(n)]
+        proto = mu_ref[:, :]            # (K, tt) broadcast target for _bc
+        mu = [mu_ref[k, :] for k in range(K)]                # (tt,) rows
+        xv = [x_ref[:, i, :] for i in range(n)]
+        P = [[P_ref[:, i, j, :] for j in range(n)] for i in range(n)]
         act = act_ref[0, :] > 0                              # (tt,)
         z_rows = [z_ref[r, :] for r in range(m)]             # (M,)
         if K == 1:
             xp, Pp = pred(xv, P)
             inno = _emit_innovation(Pp, Rtab0, obs, n, m)
             _, Sinv, _ = inno
-            cost = _emit_cost_tile([xp[obs[r]] for r in range(m)], Sinv,
-                                   z_rows, m)
+            cost = _emit_model_cost_tile(xp, Sinv, z_rows, obs, m, 0)
             assoc = _emit_greedy_assign(cost, act_ref[0, :], zv_ref[0, :],
                                         gate, rounds)
             zk = _emit_gather_assigned(assoc, z_rows, m)
@@ -812,35 +853,33 @@ def make_imm_frame_kernel(models, trans, gate: float, rounds: int,
             upd = (assoc >= 0) & act
             mu_parts = cbar_parts = None
         else:
-            dt_ = proto.dtype
-            tabv = [jnp.concatenate([jnp.full((tt,), float(v), dt_)
-                                     for v in row]) for row in V]
+            tabv = _imm_table_slabs(V, tt, proto.dtype)
             Ftab, Qtab, Rtab = (_resolve_mat(entries[nm], tabv)
                                 for nm in ("F", "Q", "R"))
             x_mix, P_mix, cbar_parts = _emit_imm_mix(
-                xv, P, mu, Pi, n, K, tt, symmetrize)
+                xv, P, mu, Pi, n, K, symmetrize)
             xp = _emit_matvec(Ftab, x_mix, n)
             Pp = _emit_predict_cov(Ftab, P_mix, Qtab, n, symmetrize)
             inno = _emit_innovation(Pp, Rtab, obs, n, m)
             _, Sinv, _ = inno
-            d = _emit_cost_tile([xp[obs[r]] for r in range(m)], Sinv,
-                                z_rows, m)                   # (M, K·tt)
-            # cbar-weighted gate: sum_k cbar_k · d_k, folded over slabs
+            # cbar-weighted gate: sum_k cbar_k · d_k over the model rows
             cost = None
             for k in range(K):
-                t = _col(cbar_parts[k]) * d[:, k * tt:(k + 1) * tt]
+                t = _col(cbar_parts[k]) * _emit_model_cost_tile(
+                    xp, Sinv, z_rows, obs, m, k)              # (M, tt)
                 cost = t if cost is None else cost + t
             assoc = _emit_greedy_assign(cost, act_ref[0, :], zv_ref[0, :],
                                         gate, rounds)
-            zk1 = _emit_gather_assigned(assoc, z_rows, m)    # (tt,) each
-            zk = [jnp.concatenate([q] * K) for q in zk1]
+            # every model row sees the same assigned measurement: the
+            # (tt,) rows broadcast against the (K, tt) slabs
+            zk = _emit_gather_assigned(assoc, z_rows, m)
             xn, Pn, ll = _emit_update(xp, Pp, zk, Rtab, obs, n, m,
                                       symmetrize, True, inno=inno)
-            mu_parts = _emit_mode_posterior(cbar_parts, ll, K, tt)
+            mu_parts = _emit_mode_posterior(cbar_parts, ll, K)
             upd = (assoc >= 0) & act
         # coasting select, exactly bank.update_imm_bank: predicted x̂/P̂
         # where a lane got no measurement, mu <- the Markov cbar
-        uL = upd if K == 1 else jnp.concatenate([upd] * K)
+        uL = jnp.broadcast_to(upd, (K, tt))
         xs = [jnp.where(uL, _bc(xn[i], proto), _bc(xp[i], proto))
               for i in range(n)]
         Ps = [[None] * n for _ in range(n)]
@@ -852,20 +891,19 @@ def make_imm_frame_kernel(models, trans, gate: float, rounds: int,
                     Ps[j][i] = Ps[i][j]
         lane1 = act_ref[0, :]                                # float (tt,)
         if K == 1:
-            mu_sel = [mu]
-            xc = xs
+            mu_sel = [mu[0]]
+            xc = [u[0] for u in xs]
         else:
             mu_sel = [jnp.where(upd, _bc(mu_parts[k], lane1),
                                 _bc(cbar_parts[k], lane1)) for k in range(K)]
-            xc = [_emit_dot(mu_sel,
-                            [u[k * tt:(k + 1) * tt] for k in range(K)], K)
+            xc = [_emit_dot(mu_sel, [u[k] for k in range(K)], K)
                   for u in xs]
         mu_out[:, :] = jnp.stack([_bc(p, lane1) for p in mu_sel])
         for i in range(n):
-            x_out[:, i, :] = xs[i].reshape(K, tt)
+            x_out[:, i, :] = xs[i]
             xc_out[i, :] = _bc(xc[i], lane1)
             for j in range(n):
-                P_out[:, i, j, :] = Ps[i][j].reshape(K, tt)
+                P_out[:, i, j, :] = Ps[i][j]
         a_out[0, :] = assoc
 
     return kernel
@@ -958,16 +996,15 @@ def make_imm_scan_kernel(models, trans, T: int, symmetrize: bool = True,
     VMEM-resident across frames.
 
     Layout: blocks arrive as x (K, n, tt), P (K, n, n, tt), mu (K, tt)
-    with tt tracks per program; in-kernel every state entry flattens to
-    ONE (K·tt,) lane vector, model-major — the K hypotheses of a track
-    live at the fixed stride tt in the padded bank, so model i's slab is
-    a static slice. That keeps the entire per-frame op stream 1-D
-    same-shape elementwise (the class the backend fuses like the
+    with tt tracks per program; in-kernel every state entry is ONE
+    (K, tt) slab, model-major — row i holds model i's hypotheses of the
+    tile's tracks, a static row read. That keeps the entire per-frame op
+    stream same-shape elementwise (the class the backend fuses like the
     single-model kernels). The per-model F/Q/R constants fold through
     ``plan_imm_tables``: entries shared by every model stay trace-time
     Python floats (zeros pruned, exactly the single-model emit), entries
     that differ materialize ONCE, outside the time loop, as
-    loop-invariant (K·tt,) vectors — so the K model-conditioned
+    loop-invariant (K, tt) slabs — so the K model-conditioned
     predict+updates emit ONE op stream whose length is independent of K.
     The (K, K) Markov transition matrix folds to float literals inside
     ``_emit_imm_mix``.
@@ -985,10 +1022,12 @@ def make_imm_scan_kernel(models, trans, T: int, symmetrize: bool = True,
     ``make_scan_kernel``'s op stream (the ``imm_scan`` stage reduces
     bitwise to ``fused_scan``, nonlinear members included).
 
-    ``with_valid`` adds a (T, 1, tt) 0/1 measurement-validity input: an
-    invalid frame coasts — the carry keeps the predicted x̂/P̂ and the
-    Markov-predicted cbar (the tracker's no-measurement semantics), via
-    a mul/add select (no control flow, static shapes).
+    ``with_valid``: the zs block carries one more row, a 0/1
+    measurement validity (a (T, 1, tt) block of its own crashes the
+    Mosaic compiler). An invalid frame coasts — the carry keeps the
+    predicted x̂/P̂ and the Markov-predicted cbar (the tracker's
+    no-measurement semantics), via a lane select (no control flow,
+    static shapes).
     """
     K = len(models)
     n, m = models[0].n, models[0].m
@@ -1009,25 +1048,17 @@ def make_imm_scan_kernel(models, trans, T: int, symmetrize: bool = True,
         pred = Rtab0 = None
     Pi = [[float(v) for v in row] for row in np.asarray(trans, np.float64)]
 
-    def kernel(x_ref, P_ref, mu_ref, zs_ref, *rest):
-        if with_valid:
-            vs_ref, xs_out, x_fin, P_fin, mu_fin = rest
-        else:
-            xs_out, x_fin, P_fin, mu_fin = rest
+    def kernel(x_ref, P_ref, mu_ref, zs_ref, xs_out, x_fin, P_fin, mu_fin):
         tt = x_ref.shape[-1]
-        L = K * tt
-        mu0 = mu_ref[:, :].reshape(L)
-        proto = mu0  # (K·tt,) broadcast target for _bc
-        xv0 = [x_ref[:, i, :].reshape(L) for i in range(n)]
-        P0 = [[P_ref[:, i, j, :].reshape(L) for j in range(n)]
-              for i in range(n)]
+        proto = mu_ref[:, :]  # (K, tt) broadcast target for _bc
+        mu0 = [mu_ref[k, :] for k in range(K)]              # (tt,) rows
+        xv0 = [x_ref[:, i, :] for i in range(n)]
+        P0 = [[P_ref[:, i, j, :] for j in range(n)] for i in range(n)]
         if K > 1:
             # materialize the model-varying constants once, OUTSIDE the
             # time loop: V[e] (one float per model) -> a loop-invariant
-            # (K·tt,) vector whose slab k is the constant for model k
-            dt_ = proto.dtype
-            tabv = [jnp.concatenate([jnp.full((tt,), float(v), dt_)
-                                     for v in row]) for row in V]
+            # (K, tt) slab whose row k is the constant for model k
+            tabv = _imm_table_slabs(V, tt, proto.dtype)
             Ftab, Qtab, Rtab = (_resolve_mat(entries[nm], tabv)
                                 for nm in ("F", "Q", "R"))
         else:
@@ -1035,40 +1066,40 @@ def make_imm_scan_kernel(models, trans, T: int, symmetrize: bool = True,
 
         def body(t, carry):
             xv, P, mu = carry
-            zt = zs_ref[pl.ds(t, 1)]  # (1, m, tt)
+            zt = zs_ref[pl.ds(t, 1)]  # (1, m [+1], tt)
+            # every model row sees the same measurement: the (tt,) rows
+            # broadcast against the (K, tt) slabs
             zr = [zt[0, r, :] for r in range(m)]
             if K == 1:
                 xp, Pp = pred(xv, P)
                 xn, Pn = _emit_update(xp, Pp, zr, Rtab, obs, n, m,
                                       symmetrize, False)
             else:
-                # every model slab sees the same measurement
-                z = [jnp.concatenate([q] * K) for q in zr]
                 x_mix, P_mix, cbar_parts = _emit_imm_mix(
-                    xv, P, mu, Pi, n, K, tt, symmetrize)
+                    xv, P, mu, Pi, n, K, symmetrize)
                 xp = _emit_matvec(Ftab, x_mix, n)
                 Pp = _emit_predict_cov(Ftab, P_mix, Qtab, n, symmetrize)
-                xn, Pn, ll = _emit_update(xp, Pp, z, Rtab, obs, n, m,
+                xn, Pn, ll = _emit_update(xp, Pp, zr, Rtab, obs, n, m,
                                           symmetrize, True)
-                mu_parts = _emit_mode_posterior(cbar_parts, ll, K, tt)
+                mu_parts = _emit_mode_posterior(cbar_parts, ll, K)
             if with_valid:
                 # coasting select: x̂/P̂ where v=0, x'/P' where v=1; mu
                 # falls back to the Markov-predicted cbar (still
                 # normalized; matches bank.update_imm_bank coasting)
-                v = vs_ref[pl.ds(t, 1)][0, 0, :]
-                vL = v if K == 1 else jnp.concatenate([v] * K)
-                nvL = 1.0 - vL
-                xn = [vL * a + nvL * b for a, b in zip(xn, xp)]
+                v = zs_ref[t, m, :] > 0                        # (tt,)
+                vK = jnp.broadcast_to(v, (K, tt))
+                xn = [jnp.where(vK, _bc(a, proto), _bc(b, proto))
+                      for a, b in zip(xn, xp)]
                 Pc = [[None] * n for _ in range(n)]
                 for i in range(n):
                     for j in (range(i, n) if symmetrize else range(n)):
-                        Pc[i][j] = vL * Pn[i][j] + nvL * Pp[i][j]
+                        Pc[i][j] = jnp.where(vK, _bc(Pn[i][j], proto),
+                                             _bc(Pp[i][j], proto))
                         if symmetrize:
                             Pc[j][i] = Pc[i][j]
                 Pn = Pc
                 if K > 1:
-                    nv = 1.0 - v
-                    mu_parts = [v * a + nv * b
+                    mu_parts = [jnp.where(v, a, b)
                                 for a, b in zip(mu_parts, cbar_parts)]
             # broadcast constant-folded entries: uniform carry structure
             xn = [_bc(u, proto) for u in xn]
@@ -1076,21 +1107,21 @@ def make_imm_scan_kernel(models, trans, T: int, symmetrize: bool = True,
             # moment-matched combined estimate, (tt,) per state dim
             if K == 1:
                 mu_new = mu
-                xc = xn
+                xc = [u[0] for u in xn]
             else:
-                mu_new = jnp.concatenate(mu_parts)
-                xc = [_emit_dot(mu_parts,
-                                [u[k * tt:(k + 1) * tt] for k in range(K)],
-                                K) for u in xn]
+                mu_new = mu_parts
+                xc = [_emit_dot(mu_parts, [u[k] for k in range(K)], K)
+                      for u in xn]
             xs_out[pl.ds(t, 1)] = jnp.stack(xc)[None]
             return xn, Pn, mu_new
 
         xT, PT, muT = jax.lax.fori_loop(0, T, body, (xv0, P0, mu0))
-        mu_fin[:, :] = muT.reshape(K, tt)
+        for k in range(K):
+            mu_fin[k, :] = muT[k]
         for i in range(n):
-            x_fin[:, i, :] = xT[i].reshape(K, tt)
+            x_fin[:, i, :] = xT[i]
             for j in range(n):
-                P_fin[:, i, j, :] = PT[i][j].reshape(K, tt)
+                P_fin[:, i, j, :] = PT[i][j]
 
     return kernel
 
@@ -1098,7 +1129,7 @@ def make_imm_scan_kernel(models, trans, T: int, symmetrize: bool = True,
 @functools.partial(jax.jit, static_argnames=("model", "lane_tile",
                                              "symmetrize", "interpret"))
 def katana_bank_step(model: FilterModel, x, P, z, lane_tile: int = LANE_TILE,
-                     symmetrize: bool = True, interpret: bool = True):
+                     symmetrize: bool = True, *, interpret: bool):
     """x: (n, N); P: (n, n, N); z: (m, N) — lanes-minor (SoA) layout.
 
     N must be a multiple of lane_tile (ops.py pads)."""
@@ -1130,7 +1161,7 @@ def katana_bank_step(model: FilterModel, x, P, z, lane_tile: int = LANE_TILE,
 @functools.partial(jax.jit, static_argnames=("imm", "lane_tile",
                                              "symmetrize", "interpret"))
 def katana_bank_imm_step(imm, x, P, z, tab, lane_tile: int = LANE_TILE,
-                         symmetrize: bool = True, interpret: bool = True):
+                         symmetrize: bool = True, *, interpret: bool):
     """Multi-model fused step over stacked lanes.
 
     x: (n, L); P: (n, n, L); z: (m, L); tab: (E, L) host-folded
@@ -1170,7 +1201,7 @@ def katana_bank_imm_step(imm, x, P, z, tab, lane_tile: int = LANE_TILE,
                                              "symmetrize", "interpret"))
 def katana_bank_scan_step(model: FilterModel, x, P, zs,
                           lane_tile: int = LANE_TILE,
-                          symmetrize: bool = True, interpret: bool = True):
+                          symmetrize: bool = True, *, interpret: bool):
     """Whole-sequence fused scan, one pallas_call per sequence.
 
     x: (n, N); P: (n, n, N); zs: (T, m, N) — lanes-minor (SoA) layout.
@@ -1179,11 +1210,11 @@ def katana_bank_scan_step(model: FilterModel, x, P, zs,
     The grid tiles N only; the time loop runs INSIDE the kernel, so the
     covariance bank stays VMEM-resident across all T frames (one HBM
     read of P at entry + one write at exit, vs 2·T round-trips for the
-    per-frame dispatch). The zs/xs blocks are whole-T VMEM blocks —
-    (T·(m+n)·lane_tile·4 bytes per program), which bounds T to a few
-    thousand frames per dispatch on real TPUs; ops.katana_bank_sequence
-    chunks longer streams. N must be a multiple of lane_tile (ops.py
-    pads)."""
+    per-frame dispatch). The zs/xs blocks are whole-T VMEM blocks,
+    which bounds T to a few hundred frames per dispatch on a TPU
+    (``scan_time_chunk`` has the arithmetic: 384 at cv-6 and 256
+    lanes); ops.katana_bank_sequence chunks longer streams. N must be
+    a multiple of lane_tile (ops.py pads)."""
     n, m = model.n, model.m
     T = zs.shape[0]
     N = x.shape[-1]
@@ -1213,21 +1244,22 @@ def katana_bank_scan_step(model: FilterModel, x, P, zs,
 
 
 @functools.partial(jax.jit, static_argnames=("imm", "lane_tile",
-                                             "symmetrize", "interpret"))
-def katana_bank_imm_scan_step(imm, x, P, mu, zs, vs=None,
-                              lane_tile: int = LANE_TILE,
+                                             "symmetrize", "with_valid",
+                                             "interpret"))
+def katana_bank_imm_scan_step(imm, x, P, mu, zs, lane_tile: int = LANE_TILE,
                               symmetrize: bool = True,
-                              interpret: bool = True):
+                              with_valid: bool = False, *,
+                              interpret: bool):
     """Whole-sequence fused IMM scan, one pallas_call per sequence.
 
     x: (K, n, N); P: (K, n, n, N); mu: (K, N); zs: (T, m, N) — the track
     index N lanes-minor; ``lane_tile`` counts TRACKS per program, whose
-    block flattens in-kernel to K·lane_tile model-major lanes (the K
-    hypotheses of a track at stride lane_tile — see
-    ``make_imm_scan_kernel``). ``vs``, if given, is a (T, 1, N) 0/1
-    validity stream: invalid frames coast (predict only, mu <- cbar).
-    Returns (xs (T, n, N) moment-matched combined estimates, x_fin,
-    P_fin, mu_fin).
+    block is read as (K, lane_tile) model-major slabs (see
+    ``make_imm_scan_kernel``); on TPU it must be a multiple of 128 or
+    the whole N. With ``with_valid`` zs is (T, m + 1, N), its last row a
+    0/1 validity stream: invalid frames coast (predict only,
+    mu <- cbar). Returns (xs (T, n, N) moment-matched combined
+    estimates, x_fin, P_fin, mu_fin).
 
     The grid tiles N only; mixing, the K predict+updates, the mode
     posterior and the combination all run INSIDE the kernel's time loop,
@@ -1238,27 +1270,21 @@ def katana_bank_imm_scan_step(imm, x, P, mu, zs, vs=None,
     K· the block bytes); ``ops.katana_imm_sequence`` chunks longer
     streams."""
     K, n = imm.K, imm.n
-    m = imm.m
-    T = zs.shape[0]
-    N = x.shape[-1]
+    T, rows, N = zs.shape
     assert N % lane_tile == 0, (N, lane_tile)
+    assert rows == imm.m + with_valid, (zs.shape, imm.m, with_valid)
     grid = (N // lane_tile,)
     kern = make_imm_scan_kernel(imm.models, imm.trans, T, symmetrize,
-                                with_valid=vs is not None)
-    in_specs = [
-        pl.BlockSpec((K, n, lane_tile), lambda i: (0, 0, i)),
-        pl.BlockSpec((K, n, n, lane_tile), lambda i: (0, 0, 0, i)),
-        pl.BlockSpec((K, lane_tile), lambda i: (0, i)),
-        pl.BlockSpec((T, m, lane_tile), lambda i: (0, 0, i)),
-    ]
-    args = [x, P, mu, zs]
-    if vs is not None:
-        in_specs.append(pl.BlockSpec((T, 1, lane_tile), lambda i: (0, 0, i)))
-        args.append(vs)
+                                with_valid=with_valid)
     return pl.pallas_call(
         kern,
         grid=grid,
-        in_specs=in_specs,
+        in_specs=[
+            pl.BlockSpec((K, n, lane_tile), lambda i: (0, 0, i)),
+            pl.BlockSpec((K, n, n, lane_tile), lambda i: (0, 0, 0, i)),
+            pl.BlockSpec((K, lane_tile), lambda i: (0, i)),
+            pl.BlockSpec((T, rows, lane_tile), lambda i: (0, 0, i)),
+        ],
         out_specs=[
             pl.BlockSpec((T, n, lane_tile), lambda i: (0, 0, i)),
             pl.BlockSpec((K, n, lane_tile), lambda i: (0, 0, i)),
@@ -1272,14 +1298,14 @@ def katana_bank_imm_scan_step(imm, x, P, mu, zs, vs=None,
             jax.ShapeDtypeStruct((K, N), mu.dtype),
         ],
         interpret=interpret,
-    )(*args)
+    )(x, P, mu, zs)
 
 
 @functools.partial(jax.jit, static_argnames=("model", "gate", "rounds",
                                              "symmetrize", "interpret"))
 def katana_frame_step(model: FilterModel, x, P, z, zval, act, gate: float,
-                      rounds: int, symmetrize: bool = True,
-                      interpret: bool = True):
+                      rounds: int, symmetrize: bool = True, *,
+                      interpret: bool):
     """Whole-frame fused dispatch: predict + gate + greedy-assign +
     update in one pallas_call.
 
@@ -1321,15 +1347,15 @@ def katana_frame_step(model: FilterModel, x, P, z, zval, act, gate: float,
 @functools.partial(jax.jit, static_argnames=("imm", "gate", "rounds",
                                              "symmetrize", "interpret"))
 def katana_imm_frame_step(imm, x, P, mu, z, zval, act, gate: float,
-                          rounds: int, symmetrize: bool = True,
-                          interpret: bool = True):
+                          rounds: int, symmetrize: bool = True, *,
+                          interpret: bool):
     """Whole-frame fused IMM dispatch: mix + K predicts + cbar-weighted
     gate + greedy-assign + K updates + mode posterior + combined
     estimate in one pallas_call.
 
     x: (K, n, C); P: (K, n, n, C); mu: (K, C); z: (m, M); zval: (1, M)
-    0/1; act: (1, C) 0/1 — track axis lanes-minor, model-major flatten
-    in-kernel (the ``make_imm_scan_kernel`` layout). Returns
+    0/1; act: (1, C) 0/1 — track axis lanes-minor, (K, C) model-major
+    slabs in-kernel (the ``make_imm_scan_kernel`` layout). Returns
     (x' (K, n, C), P' (K, n, n, C), mu' (K, C), x_c (n, C),
     assoc (1, C) int32). grid=(1,) for the same global-argmin reason as
     ``katana_frame_step``."""
@@ -1368,8 +1394,8 @@ def katana_imm_frame_step(imm, x, P, mu, z, zval, act, gate: float,
 
 
 @functools.partial(jax.jit, static_argnames=("gate", "rounds", "interpret"))
-def greedy_assign_step(cost, valid, gate: float, rounds: int,
-                       interpret: bool = True):
+def greedy_assign_step(cost, valid, gate: float, rounds: int, *,
+                       interpret: bool):
     """Standalone dispatch of the in-kernel greedy assignment
     (``_emit_greedy_assign``) for direct equivalence testing against
     ``tracker.greedy_assign``: cost (M, C) lanes-minor, valid (M, C)

@@ -36,17 +36,16 @@ Dispatch granularities:
         equivalence testing against ``tracker.greedy_assign``.
 
 Execution mode: every op's ``interpret`` parameter defaults to ``None``
-= "resolve from the active execution mode" (``repro.execmode``: the
-``KATANA_MODE`` env var / ``TrackerConfig.mode``, with a capability
-probe so a ``compiled`` request on a backend that can't lower Pallas —
-CPU included — falls back to the interpreter LOUDLY, never silently).
-Pass ``interpret=True``/``False`` to pin a path explicitly (the kernel
-equivalence tests do). Likewise ``lane_tile``/``time_chunk`` default to
-0 = "consult the autotuned table" (``autotune.tuned.json``, keyed on
-kernel x bank size x backend x mode), falling back to the static
-defaults when no measurement matches. The raw ``kernel.py`` step
-functions below this layer stay mode-unaware (explicit ``interpret``
-only); ops is where policy is resolved.
+= "the backend's mode" (``repro.execmode``): compiled on a TPU, with no
+probe and no fallback — a kernel the TPU compiler refuses raises — and
+the Pallas interpreter on CPU. ``interpret=True`` pins the interpreter
+(CPU only: the kernel equivalence tests do), ``interpret=False`` pins
+compilation (the compile rehearsals for a described TPU do). Likewise
+``lane_tile``/``time_chunk`` default to 0 = "consult the autotuned
+table" (``autotune.tuned.json``, keyed on kernel x bank size x backend
+x mode), falling back to the static defaults when no measurement
+matches. The raw ``kernel.py`` step functions below this layer take
+``interpret`` as a required keyword; ops is where policy is resolved.
 """
 from __future__ import annotations
 
@@ -73,11 +72,13 @@ from repro.kernels.katana_bank.kernel import (
     katana_frame_step,
     katana_imm_frame_step,
     plan_imm_tables,
+    scan_time_chunk,
 )
 
-# the frame kernels run grid=(1,) over the whole bank, so the lane pad
-# only needs to keep the minor axis register-friendly — 128, not the
-# scan kernels' per-program LANE_TILE
+# one 128-lane f32 register width. The frame kernels run grid=(1,) over
+# the whole bank, so their lane pad only needs to keep the minor axis
+# register-friendly — 128, not the scan kernels' per-program LANE_TILE;
+# it is also the least IMM scan track tile a TPU block accepts
 FRAME_LANE_PAD = 128
 
 
@@ -143,15 +144,17 @@ def katana_bank_sequence(model: FilterModel, zs, x0, P0,
     ceil(T / time_chunk) dispatches with (x, P) carried between them —
     the bank still only round-trips HBM once per CHUNK, not per frame.
     ``lane_tile=0`` / ``time_chunk=0`` consult the autotuned table
-    (static fallbacks LANE_TILE / 4096); ``interpret=None`` resolves
-    from the active execution mode.
+    (static fallbacks LANE_TILE / the VMEM-fitting
+    ``kernel.scan_time_chunk``); ``interpret=None`` resolves from the
+    active execution mode.
     """
     N = jnp.shape(zs)[1]
     interpret = resolve_interpret(interpret)
     lane_tile = lane_tile or tuned_lane_tile("katana_bank_sequence", N,
                                              LANE_TILE)
-    time_chunk = time_chunk or tuned_time_chunk("katana_bank_sequence", N,
-                                                4096)
+    time_chunk = time_chunk or tuned_time_chunk(
+        "katana_bank_sequence", N, scan_time_chunk(model.n, model.m,
+                                                   lane_tile))
     return _katana_bank_sequence(model, zs, x0, P0, lane_tile=lane_tile,
                                  symmetrize=symmetrize, interpret=interpret,
                                  return_final=return_final,
@@ -372,6 +375,15 @@ def _katana_bank_imm(imm: IMMModel, x, P, z, lane_tile: int,
             ll[0, :L].reshape(K, N))
 
 
+def imm_track_tile(K: int) -> int:
+    """Default tracks per IMM scan program: the largest power of two
+    <= LANE_TILE // K (a power of two even when K doesn't divide the
+    lane tile: K=3 would otherwise give an 85-wide block), but never
+    below one 128-lane register width — a TPU block's minor dim must be
+    a multiple of 128."""
+    return max(FRAME_LANE_PAD, 1 << (LANE_TILE // K).bit_length() - 1)
+
+
 def katana_imm_sequence(imm: IMMModel, zs, x0, P0, mu0=None, valid=None,
                         lane_tile: int = 0, symmetrize: bool = True,
                         interpret: Optional[bool] = None,
@@ -392,13 +404,12 @@ def katana_imm_sequence(imm: IMMModel, zs, x0, P0, mu0=None, valid=None,
 
     ``lane_tile`` here counts TRACKS per program (each program holds all
     K model slabs of its tracks, K·lane_tile lanes); the default 0
-    first consults the autotuned table, then falls back to LANE_TILE//K
-    so every program keeps the same lane footprint as the single-model
-    kernels regardless of K. The ``time_chunk`` fallback (64) is
-    deliberately smaller than the single-model sequence's: the IMM scan
-    carries K· the block bytes per frame, and bounded chunks also keep
-    the backend's in-loop output-block updates from degrading on long
-    streams.
+    first consults the autotuned table, then falls back to
+    ``imm_track_tile`` (256 at K=1, 128 for K>1). The ``time_chunk``
+    fallback (64) bounds the per-program VMEM instead: at K=4, cv-9 and
+    128 tracks the streamed zs/xs/valid blocks take
+    64 · (8 + 16 + 8) rows · 128 · 4 B · 2 buffers = 2 MiB, the x/P/mu
+    state blocks about 1.3 MiB, well inside the 16 MiB scoped VMEM.
 
     Unlike ``imm_bank_sequence`` (one katana_bank_imm dispatch plus XLA
     mixing per frame), the mixing and mode-posterior algebra run INSIDE
@@ -409,13 +420,8 @@ def katana_imm_sequence(imm: IMMModel, zs, x0, P0, mu0=None, valid=None,
     """
     N = jnp.shape(zs)[1]
     interpret = resolve_interpret(interpret)
-    if not lane_tile:
-        lane_tile = tuned_lane_tile("katana_imm_sequence", N, 0)
-    if not lane_tile:
-        # largest power of two <= LANE_TILE / K: keeps the BlockSpec
-        # minor dim lane-register-friendly even when K doesn't divide
-        # the lane tile (K=3 would otherwise give an 85-wide block)
-        lane_tile = 1 << max(3, (LANE_TILE // imm.K).bit_length() - 1)
+    lane_tile = lane_tile or tuned_lane_tile("katana_imm_sequence", N,
+                                             imm_track_tile(imm.K))
     time_chunk = time_chunk or tuned_time_chunk("katana_imm_sequence", N, 64)
     return _katana_imm_sequence(imm, zs, x0, P0, mu0, valid,
                                 lane_tile=lane_tile, symmetrize=symmetrize,
@@ -456,15 +462,16 @@ def _katana_imm_sequence(imm: IMMModel, zs, x0, P0, mu0, valid,
         # update before the select — zero it so a NaN-encoded "no
         # detection" in a replay log cannot poison the carry via 0·NaN
         zs = jnp.where(jnp.asarray(valid, bool)[:, :, None], zs, 0.0)
-    zs_s = _pad_to(zs.transpose(0, 2, 1), N_pad)        # (T, m, N_pad)
-    vs_s = (None if valid is None else
-            _pad_to(jnp.asarray(valid, zs.dtype)[:, None, :], N_pad))
+        # the validity rides along as one more measurement row
+        zs = jnp.concatenate(
+            [zs, jnp.asarray(valid, zs.dtype)[:, :, None]], axis=2)
+    zs_s = _pad_to(zs.transpose(0, 2, 1), N_pad)        # (T, m [+1], N_pad)
     chunks = []
     for t0 in range(0, T, time_chunk):
-        vt = None if vs_s is None else vs_s[t0:t0 + time_chunk]
         xs, xs_s, Ps_s, mu_s = katana_bank_imm_scan_step(
-            imm, xs_s, Ps_s, mu_s, zs_s[t0:t0 + time_chunk], vt,
-            lane_tile=lane_tile, symmetrize=symmetrize, interpret=interpret)
+            imm, xs_s, Ps_s, mu_s, zs_s[t0:t0 + time_chunk],
+            lane_tile=lane_tile, symmetrize=symmetrize,
+            with_valid=valid is not None, interpret=interpret)
         chunks.append(xs)
     xs = chunks[0] if len(chunks) == 1 else jnp.concatenate(chunks)
     out = xs[:, :, :N].transpose(0, 2, 1)               # (T, N, n)

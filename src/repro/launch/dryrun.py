@@ -100,8 +100,6 @@ def _compile_cell(cfg, shape, run, ctx):
 
 def _cost_dict(compiled):
     ca = compiled.cost_analysis()
-    if isinstance(ca, (list, tuple)):  # jax<=0.4 returns [dict] per device
-        ca = ca[0] if ca else {}
     txt = compiled.as_text()
     cc = hlo_lib.collective_census(txt)
     tot = hlo_lib.totals(cc)

@@ -5,18 +5,8 @@ state (the dry-run must set XLA_FLAGS before any jax initialization).
 """
 from __future__ import annotations
 
-from repro import compat
-
-try:  # jax >= 0.5 explicit axis types; older releases have neither
-    from jax.sharding import AxisType
-except ImportError:  # pragma: no cover - version-dependent
-    AxisType = None
-
-
-def _axis_type_kwargs(n_axes: int):
-    if AxisType is None:
-        return {}
-    return {"axis_types": (AxisType.Auto,) * n_axes}
+import jax
+from jax.sharding import AxisType
 
 
 def make_production_mesh(*, multi_pod: bool = False):
@@ -24,10 +14,11 @@ def make_production_mesh(*, multi_pod: bool = False):
     Multi-pod: (2, 16, 16) ('pod','data','model') = 512 chips."""
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return compat.make_mesh(shape, axes, **_axis_type_kwargs(len(axes)))
+    return make_mesh(shape, axes)
 
 
 def make_mesh(shape, axes):
-    """Arbitrary mesh for tests/elastic restore."""
-    return compat.make_mesh(tuple(shape), tuple(axes),
-                            **_axis_type_kwargs(len(axes)))
+    """Arbitrary mesh over the first prod(shape) devices, every axis
+    auto-sharded (tests, the sharded engines, elastic restore)."""
+    return jax.make_mesh(tuple(shape), tuple(axes),
+                         axis_types=(AxisType.Auto,) * len(axes))

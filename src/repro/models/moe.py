@@ -21,7 +21,6 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro import compat
 from repro.configs.base import MoEConfig
 
 
@@ -181,12 +180,12 @@ def apply_moe(p: Dict, x: jnp.ndarray, cfg: MoEConfig, act: str,
     if "w_gate" in p:
         args.append(p["w_gate"])
         in_specs.append(wspec_in)
-    out, aux = compat.shard_map(
+    out, aux = jax.shard_map(
         shard_fn,
         mesh=mesh,
         in_specs=tuple(in_specs),
         out_specs=(P(dp if dp else None, None, None), P()),
-        check=False,  # all_gather over the FSDP axes un-varies the
+        check_vma=False,  # all_gather over the FSDP axes un-varies the
         # weights; the static VMA checker can't see that.
     )(*args)
     return out, aux
@@ -230,9 +229,9 @@ def _apply_moe_tp2d(p: Dict, x: jnp.ndarray, cfg: MoEConfig, act: str,
     if "w_gate" in p:
         args.append(p["w_gate"])
         in_specs.append(P(tp, None, dpx))
-    out, aux = compat.shard_map(
+    out, aux = jax.shard_map(
         shard_fn, mesh=mesh, in_specs=tuple(in_specs),
         out_specs=(P(None, None, None), P()),
-        check=False,
+        check_vma=False,
     )(*args)
     return out, aux

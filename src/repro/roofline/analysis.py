@@ -20,7 +20,8 @@ from __future__ import annotations
 from dataclasses import dataclass
 from typing import Dict, Optional
 
-# --- TPU v5e per-chip constants (assignment-specified) ---
+# --- TPU v5e per-chip constants (Google Cloud documentation, "TPU v5e":
+# 197 TFLOP/s bf16, 16 GB HBM at 819 GB/s, 1,600 Gbit/s of ICI) ---
 PEAK_FLOPS_BF16 = 197e12      # FLOP/s
 HBM_BW = 819e9                # B/s
 ICI_BW_PER_LINK = 50e9        # B/s per link (~)
@@ -30,28 +31,36 @@ ICI_BW = ICI_BW_PER_LINK * ICI_LINKS
 
 @dataclass(frozen=True)
 class Machine:
-    """Per-backend roofline peaks. The cpu entry is an order-of-
-    magnitude reference for a few AVX2 cores (enough to classify a
-    program compute- vs memory-bound; not a calibrated model of any
-    particular host), the tpu_v5e entry the assignment-specified chip."""
+    """Roofline peaks of one device kind, with where they come from."""
     name: str
     peak_flops: float   # FLOP/s
     mem_bw: float       # B/s
     ici_bw: float       # B/s (collective injection; ~0 disables the term)
+    source: str = ""
 
 
+# Keyed by ``jax.Device.device_kind`` (TPU v5e reports "TPU v5 lite").
 MACHINES = {
-    "tpu_v5e": Machine("tpu_v5e", PEAK_FLOPS_BF16, HBM_BW, ICI_BW),
-    "cpu": Machine("cpu", 1.0e11, 2.0e10, 1.0e9),
+    "TPU v5 lite": Machine(
+        "TPU v5 lite", PEAK_FLOPS_BF16, HBM_BW, ICI_BW,
+        source='Google Cloud documentation, "TPU v5e" (bf16 peak)'),
+    "cpu": Machine(
+        "cpu", 1.0e11, 2.0e10, 1.0e9,
+        source="order-of-magnitude reference for a few AVX2 cores "
+               "(classifies compute- vs memory-bound; not a calibrated "
+               "model of any host)"),
 }
 
 
-def machine_for_backend(backend: str) -> Machine:
-    """Map a jax backend name to its roofline Machine (TPU backends to
-    the v5e reference chip, anything unknown to the cpu reference)."""
-    if backend.startswith("tpu"):
-        return MACHINES["tpu_v5e"]
-    return MACHINES.get(backend, MACHINES["cpu"])
+def machine_for(device_kind: str) -> Machine:
+    """The roofline peaks of a ``device_kind``. A device that is not in
+    the table is an error, never a default."""
+    try:
+        return MACHINES[device_kind]
+    except KeyError:
+        raise KeyError(f"no roofline peaks for device_kind "
+                       f"{device_kind!r}: add it to MACHINES with its "
+                       f"source") from None
 
 
 @dataclass

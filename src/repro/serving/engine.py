@@ -34,12 +34,12 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import NamedSharding, PartitionSpec as P
 
-from repro import compat
 from repro.core import bank as bank_lib
 from repro.core.bank import BankState, init_bank, init_imm_bank
 from repro.core.filters import FilterModel, IMMModel, as_imm
 from repro.core.tracker import (FrameResult, TrackerConfig, frame_step,
                                 imm_frame_step, make_multi_sensor_step)
+from repro.execmode import active_mode
 from repro.kernels.katana_bank.ops import (katana_bank_sequence,
                                            katana_imm_sequence)
 from repro.sharding.rules import make_context, sensor_specs
@@ -88,10 +88,10 @@ class TrackingEngine:
     def __init__(self, model, cfg: Optional[TrackerConfig] = None):
         self.model = model
         self.cfg = cfg or TrackerConfig()
-        # the resolved execution mode (KATANA_MODE / cfg.mode): recorded
-        # here so serving telemetry can always say whether the kernels
-        # ran compiled or through the interpreter
-        self.exec_mode = self.cfg.exec_mode()
+        # the backend's execution mode: recorded here so serving
+        # telemetry can always say whether the kernels ran compiled or
+        # through the interpreter
+        self.exec_mode = active_mode()
         self.is_imm = isinstance(model, IMMModel)
         if self.is_imm:
             self.bank = init_imm_bank(model, self.cfg.capacity,
@@ -188,7 +188,7 @@ class ShardedBankEngine:
     Banks stack on a sensor axis (position 1 — after the model axis K —
     for the IMM x/P leaves, leading elsewhere: the (K, S, C, n)
     placement) that is shard_mapped over the mesh data axes
-    (``sharding.rules.sensor_specs`` + ``repro.compat.shard_map``).
+    (``sharding.rules.sensor_specs`` + ``jax.shard_map``).
     Association stays per-sensor (vmapped), sensors are independent, so
     the step carries zero collectives and every shard computes the
     bitwise-identical unsharded per-sensor frame — the pod-scale
@@ -200,7 +200,7 @@ class ShardedBankEngine:
                  cfg: Optional[TrackerConfig] = None, mesh=None):
         self.model = model
         self.cfg = cfg or TrackerConfig(capacity=64, max_meas=32)
-        self.exec_mode = self.cfg.exec_mode()
+        self.exec_mode = active_mode()
         self.n = n_sensors
         self.is_imm = isinstance(model, IMMModel)
         self.mesh = mesh
@@ -225,11 +225,11 @@ class ShardedBankEngine:
                 confirmed=self._ctx.batch_spec(2),
                 mode_probs=self._ctx.batch_spec(3),
                 x_est=self._ctx.batch_spec(3))
-            self._step = jax.jit(compat.shard_map(
+            self._step = jax.jit(jax.shard_map(
                 step, mesh=mesh,
                 in_specs=(self._bank_specs, self._ctx.batch_spec(3),
                           self._ctx.batch_spec(2)),
-                out_specs=res_specs))
+                out_specs=res_specs, check_vma=False))
             self.banks = jax.tree.map(
                 lambda x, s: jax.device_put(x, NamedSharding(mesh, s)),
                 self.banks, self._bank_specs)
@@ -304,8 +304,9 @@ class ShardedBankEngine:
         zspec = P(None, self._ctx.data_axes, None, None)
         in_specs = (self._bank_specs, zspec) + (
             (P(None, self._ctx.data_axes, None),) if has_valid else ())
-        return jax.jit(compat.shard_map(body, mesh=self.mesh,
-                                        in_specs=in_specs, out_specs=zspec))
+        return jax.jit(jax.shard_map(body, mesh=self.mesh,
+                                     in_specs=in_specs, out_specs=zspec,
+                                     check_vma=False))
 
     def replay(self, zs: np.ndarray,
                valid: Optional[np.ndarray] = None) -> np.ndarray:

@@ -1,10 +1,11 @@
 """Optional-hypothesis shim for the test suite.
 
-When ``hypothesis`` is installed the real ``given``/``settings``/``st``
-are re-exported unchanged. When it is absent (the minimal container
-image), the property tests degrade to fixed-seed parametrized cases:
+When ``hypothesis`` is installed the real ``given``/``settings``/``st``/
+``example`` are re-exported unchanged. When it is absent (the minimal
+container image), the property tests degrade to fixed-seed parametrized cases:
 ``given`` samples ``max_examples`` tuples from the strategies with a
-deterministic per-test rng and applies ``pytest.mark.parametrize``.
+deterministic per-test rng and applies ``pytest.mark.parametrize``,
+after the cases recorded with ``example``.
 Coverage shrinks (no shrinking, no adaptive search) but every property
 still runs — the suite never fails to *collect*.
 """
@@ -17,7 +18,8 @@ import numpy as np
 import pytest
 
 try:
-    from hypothesis import given, settings, strategies as st  # noqa: F401
+    from hypothesis import (example, given, settings,  # noqa: F401
+                            strategies as st)
 
     HAVE_HYPOTHESIS = True
 except ModuleNotFoundError:
@@ -43,13 +45,23 @@ except ModuleNotFoundError:
 
         return deco
 
+    def example(**kwargs):
+        def deco(fn):
+            fn._compat_examples = [kwargs] + getattr(fn, "_compat_examples",
+                                                     [])
+            return fn
+
+        return deco
+
     def given(*strategies):
         def deco(fn):
             n_ex = getattr(fn, "_compat_max_examples", 10)
             # deterministic per-test seed so failures reproduce
             rng = np.random.default_rng(zlib.crc32(fn.__name__.encode()))
             names = list(inspect.signature(fn).parameters)[: len(strategies)]
-            cases = [
+            cases = [tuple(ex[k] for k in names)
+                     for ex in getattr(fn, "_compat_examples", [])]
+            cases += [
                 tuple(s.sample(rng) for s in strategies) for _ in range(n_ex)
             ]
             return pytest.mark.parametrize(",".join(names), cases)(fn)

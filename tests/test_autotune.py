@@ -15,8 +15,8 @@ import pytest
 from repro.execmode import ExecMode
 from repro.kernels.katana_bank import autotune
 
-CPU_INTERP = ExecMode("auto", "interpret", "cpu", False, None, "x")
-TPU_COMPILED = ExecMode("auto", "compiled", "tpu", True, None, "x")
+CPU_INTERP = ExecMode("interpret", "cpu", "x")
+TPU_COMPILED = ExecMode("compiled", "tpu", "x")
 
 
 @pytest.fixture
@@ -147,8 +147,7 @@ def test_ops_defaults_consult_table(tmp_path, monkeypatch):
 
 def _bench_fixture(root, speedup_scan=4.0, speedup_frame=1.5,
                    imm_ratio=2.0, drop_frame=False):
-    meta = dict(requested="auto", mode="interpret", backend="cpu",
-                pallas_native=False, fallback=None, jax="x")
+    meta = dict(mode="interpret", backend="cpu", jax="x")
     (root / "BENCH_scan.json").write_text(json.dumps(dict(
         bench="scan_fusion", meta=meta,
         rows=[dict(kind="lkf", N=8, speedup_fused_vs_loop=speedup_scan)])))

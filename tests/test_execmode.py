@@ -1,145 +1,141 @@
-"""Execution-mode resolution: env plumbing, loud fallback, row labels.
+"""Execution-mode resolution: the backend decides, no fallback, row labels.
 
-The contract under test (src/repro/execmode.py): a single resolver
-decides interpret-vs-compiled for every kernel op; a ``compiled``
-request on a backend that can't lower Pallas falls back LOUDLY
-(``ExecModeFallbackWarning`` + non-None ``fallback``); per-BENCH-row
-labels call XLA-native paths compiled everywhere but Pallas paths
-compiled only when natively lowered. The CI compiled-mode job relies
-on every one of these properties.
+The contract under test (src/repro/execmode.py): the backend alone
+decides interpret-vs-compiled for every kernel op — compiled on TPU,
+the Pallas interpreter elsewhere — with no capability probe and no
+fallback: a kernel that cannot lower raises, and the interpreter never
+runs on a TPU backend. Per-BENCH-row labels call XLA-native paths
+compiled everywhere but Pallas paths compiled only on TPU.
 """
-import warnings
-
 import jax
 import numpy as np
 import pytest
 
-from repro.execmode import (ENV_VAR, ExecMode, ExecModeFallbackWarning,
-                            active_mode, pallas_lowering_supported,
-                            resolve_interpret, resolve_mode)
+from repro.execmode import (ExecMode, active_mode, backend_mode,
+                            resolve_interpret)
+
+
+def _lkf_bank(N=4):
+    import jax.numpy as jnp
+
+    from repro.core.filters import get_filter
+
+    model = get_filter("lkf")
+    rng = np.random.default_rng(0)
+    x = jnp.asarray(np.tile(model.x0, (N, 1)), jnp.float32)
+    P = jnp.asarray(np.tile(model.P0, (N, 1, 1)), jnp.float32)
+    z = jnp.asarray(rng.normal(size=(N, model.m)), jnp.float32)
+    return model, x, P, z
 
 
 def test_auto_resolves_to_backend_capability():
-    m = resolve_mode("auto")
-    assert m.requested == "auto"
+    m = active_mode()
     assert m.backend == jax.default_backend()
-    assert m.pallas_native == pallas_lowering_supported(m.backend)
-    # auto never warns and never records a fallback
-    assert m.fallback is None
-    assert m.mode == ("compiled" if m.pallas_native else "interpret")
+    assert m.jax_version == jax.__version__
+    assert m.mode == backend_mode(m.backend)
+    assert m.mode == ("compiled" if m.backend == "tpu" else "interpret")
+
+
+@pytest.mark.parametrize("backend,mode", [("tpu", "compiled"),
+                                          ("cpu", "interpret"),
+                                          ("gpu", "interpret")])
+def test_active_mode_follows_backend(monkeypatch, backend, mode):
+    """The backend is the only input: a TPU resolves to compiled with
+    no probe at all, every other backend to the interpreter."""
+    monkeypatch.setattr(jax, "default_backend", lambda: backend)
+    m = active_mode()
+    assert (m.backend, m.mode) == (backend, mode)
+    assert resolve_interpret(None) is (mode == "interpret")
 
 
 def test_interpret_request_is_always_honored():
-    m = resolve_mode("interpret")
-    assert m.mode == "interpret"
-    assert m.interpret is True
-    assert m.fallback is None
+    """Where the interpreter exists (every non-TPU backend) an explicit
+    ``interpret=True`` is honoured."""
+    if jax.default_backend() == "tpu":
+        with pytest.raises(ValueError, match="CPU only"):
+            resolve_interpret(True)
+        return
+    assert resolve_interpret(True) is True
+    assert active_mode().interpret is True
 
 
 def test_compiled_request_is_never_silent():
-    """compiled either really compiles or records a loud fallback —
-    there is no third state. (_resolve is lru_cached, so the warning
-    fires once per process: clear the cache to observe it here.)"""
-    from repro.execmode import _resolve
-
-    _resolve.cache_clear()
-    try:
-        with warnings.catch_warnings(record=True) as caught:
-            warnings.simplefilter("always")
-            m = resolve_mode("compiled")
-        fired = [w for w in caught
-                 if issubclass(w.category, ExecModeFallbackWarning)]
-        if m.pallas_native:
-            assert m.mode == "compiled"
-            assert m.fallback is None
-            assert not fired
-        else:
-            assert m.mode == "interpret"
-            assert m.fallback == f"pallas-lowering-unsupported:{m.backend}"
-            assert fired, "fallback must warn loudly"
-    finally:
-        _resolve.cache_clear()  # order-independence for other tests
+    """``interpret=False`` is always honoured as asked (compile
+    rehearsals on CPU need it); it is never turned into the
+    interpreter."""
+    assert resolve_interpret(False) is False
 
 
-def test_env_var_drives_active_mode(monkeypatch):
-    monkeypatch.setenv(ENV_VAR, "interpret")
-    assert active_mode().requested == "interpret"
-    monkeypatch.setenv(ENV_VAR, "AUTO")  # case/space tolerant
-    assert active_mode().requested == "auto"
-    monkeypatch.delenv(ENV_VAR)
-    assert active_mode().requested == "auto"
+def test_interpreter_refused_on_tpu_backend(monkeypatch):
+    """An explicit ``interpret=True`` can never reach a kernel on a TPU
+    backend; ``interpret=False`` (compile for the chip) is always
+    allowed, as compile rehearsals on CPU need it."""
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    with pytest.raises(ValueError, match="CPU only"):
+        resolve_interpret(True)
+    assert resolve_interpret(False) is False
 
 
-def test_bad_mode_rejected(monkeypatch):
-    monkeypatch.setenv(ENV_VAR, "turbo")
-    with pytest.raises(ValueError, match="turbo"):
-        active_mode()
+def test_lowering_failure_raises_instead_of_interpreting(monkeypatch):
+    """A backend that resolves to compiled but cannot lower the kernel
+    raises; nothing falls back to the interpreter."""
+    from repro.kernels.katana_bank.ops import katana_bank
+
+    if jax.default_backend() == "tpu":
+        pytest.skip("the TPU lowers the kernel")
+    model, x, P, z = _lkf_bank()
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    with pytest.raises(ValueError, match="interpret"):
+        katana_bank(model, x, P, z)
 
 
 def test_explicit_interpret_beats_mode():
-    """Tests pin the interpreter with interpret=True regardless of the
-    requested mode — the ops-level shim must honor that."""
-    assert resolve_interpret(True, mode="interpret") is True
-    assert resolve_interpret(False, mode="interpret") is False
-    assert resolve_interpret(None, mode="interpret") is True
+    """Tests pin the interpreter with interpret=True on CPU, and compile
+    rehearsals pin interpret=False — the ops-level shim honours both."""
+    if jax.default_backend() != "tpu":
+        assert resolve_interpret(True) is True
+    assert resolve_interpret(False) is False
+    assert resolve_interpret(None) is active_mode().interpret
 
 
 def test_row_labels_are_honest():
     """XLA rows are compiled everywhere; Pallas rows are compiled only
-    when the kernel itself lowered natively."""
-    native = ExecMode("compiled", "compiled", "tpu", True, None, "x")
-    fell_back = ExecMode("compiled", "interpret", "cpu", False,
-                         "pallas-lowering-unsupported:cpu", "x")
+    on a TPU backend."""
+    native = ExecMode("compiled", "tpu", "x")
+    interp = ExecMode("interpret", "cpu", "x")
     assert native.lowering(pallas=True) == "pallas"
     assert native.row_mode(pallas=True) == "compiled"
-    assert fell_back.lowering(pallas=True) == "pallas-interpret"
-    assert fell_back.row_mode(pallas=True) == "interpret"
-    for m in (native, fell_back):
+    assert interp.lowering(pallas=True) == "pallas-interpret"
+    assert interp.row_mode(pallas=True) == "interpret"
+    for m in (native, interp):
         assert m.lowering(pallas=False) == "xla"
         assert m.row_mode(pallas=False) == "compiled"
 
 
 def test_as_meta_round_trips_the_facts():
-    m = resolve_mode("auto")
+    m = active_mode()
     meta = m.as_meta()
-    assert meta["backend"] == m.backend
-    assert meta["mode"] == m.mode
-    assert meta["requested"] == "auto"
-    assert meta["jax"] == jax.__version__
-    assert meta["fallback"] is None
+    assert meta == dict(mode=m.mode, backend=m.backend, jax=jax.__version__)
 
 
-def test_ops_honor_resolved_mode(monkeypatch):
-    """End-to-end: KATANA_MODE threads env -> resolver -> ops wrapper
-    -> pallas_call, and the result is unchanged (same math, different
-    dispatch route is only possible where the backend lowers Pallas)."""
-    import jax.numpy as jnp
-
-    from repro.core.filters import get_filter
+def test_ops_honor_resolved_mode():
+    """End-to-end: backend -> resolver -> ops wrapper -> pallas_call.
+    An op left to the resolver gives exactly what the op pinned to the
+    backend's own mode gives."""
     from repro.kernels.katana_bank.ops import katana_bank
 
-    model = get_filter("lkf")
-    N = 4
-    rng = np.random.default_rng(0)
-    x = jnp.asarray(np.tile(model.x0, (N, 1)), jnp.float32)
-    P = jnp.asarray(np.tile(model.P0, (N, 1, 1)), jnp.float32)
-    z = jnp.asarray(rng.normal(size=(N, model.m)), jnp.float32)
-
-    x_pinned, P_pinned = katana_bank(model, x, P, z, interpret=True)
-    monkeypatch.setenv(ENV_VAR, "compiled")
-    with warnings.catch_warnings():
-        warnings.simplefilter("ignore", ExecModeFallbackWarning)
-        x_env, P_env = katana_bank(model, x, P, z)
-    np.testing.assert_allclose(np.asarray(x_env), np.asarray(x_pinned),
-                               atol=1e-5, rtol=1e-5)
-    np.testing.assert_allclose(np.asarray(P_env), np.asarray(P_pinned),
-                               atol=1e-5, rtol=1e-5)
+    model, x, P, z = _lkf_bank()
+    own = backend_mode(jax.default_backend())
+    x_pinned, P_pinned = katana_bank(model, x, P, z,
+                                     interpret=own == "interpret")
+    x_auto, P_auto = katana_bank(model, x, P, z)
+    np.testing.assert_array_equal(np.asarray(x_auto), np.asarray(x_pinned))
+    np.testing.assert_array_equal(np.asarray(P_auto), np.asarray(P_pinned))
 
 
-def test_tracker_config_carries_mode():
-    from repro.core.tracker import TrackerConfig
+def test_engine_records_the_backend_mode():
+    from repro.core.filters import get_filter
+    from repro.serving.engine import TrackingEngine
 
-    m = TrackerConfig(capacity=8, max_meas=4, mode="interpret").exec_mode()
-    assert m.requested == "interpret" and m.interpret
-    # default config defers to the environment resolver
-    assert TrackerConfig(capacity=8, max_meas=4).exec_mode() == active_mode()
+    eng = TrackingEngine(get_filter("lkf"))
+    assert eng.exec_mode == active_mode()

@@ -16,7 +16,7 @@ import pytest
 
 from repro.roofline.analysis import (HBM_BW, ICI_BW, MACHINES,
                                      PEAK_FLOPS_BF16, Machine,
-                                     machine_for_backend, terms_from,
+                                     machine_for, terms_from,
                                      terms_on)
 from repro.roofline.hlo import (collective_census, cpu_upcast_bytes,
                                 op_census, totals)
@@ -147,10 +147,18 @@ def test_terms_on_uses_machine_peaks():
 
 
 def test_machine_for_backend_mapping():
-    assert machine_for_backend("tpu") is MACHINES["tpu_v5e"]
-    assert machine_for_backend("tpu_v5e") is MACHINES["tpu_v5e"]
-    assert machine_for_backend("cpu") is MACHINES["cpu"]
-    assert machine_for_backend("unknown-thing") is MACHINES["cpu"]
+    """Peaks are keyed by ``device_kind`` with a named source; a device
+    the table does not know raises instead of borrowing a preset."""
+    v5e = machine_for("TPU v5 lite")
+    assert v5e is MACHINES["TPU v5 lite"]
+    assert (v5e.peak_flops, v5e.mem_bw) == (PEAK_FLOPS_BF16, HBM_BW)
+    assert machine_for("cpu") is MACHINES["cpu"]
+    kind = jax.devices()[0].device_kind
+    assert machine_for(kind).name == kind
+    assert all(m.source for m in MACHINES.values())
+    for unknown in ("tpu", "TPU v4", "unknown-thing"):
+        with pytest.raises(KeyError, match="device_kind"):
+            machine_for(unknown)
 
 
 # ---------------------------------------------------------------------------

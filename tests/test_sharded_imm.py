@@ -22,7 +22,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro.compat import make_mesh
+from repro.launch.mesh import make_mesh
 from repro.core import bank as bank_lib
 from repro.core.bank import IMMBankState, init_imm_bank, replay_imm_bank
 from repro.core.filters import as_imm, make_cv9_lkf, make_imm

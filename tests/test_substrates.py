@@ -190,10 +190,8 @@ def test_compressed_psum_ring():
     def f(x):
         return compressed_psum(x, "pod")
 
-    from repro import compat
-
-    sharded = compat.shard_map(f, mesh=mesh, in_specs=P(), out_specs=P(),
-                               check=False)
+    sharded = jax.shard_map(f, mesh=mesh, in_specs=P(), out_specs=P(),
+                            check_vma=False)
     x = jnp.asarray(np.random.default_rng(0).normal(size=(8, 16)),
                     jnp.float32)
     out = sharded(x)
