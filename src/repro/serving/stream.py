@@ -45,6 +45,11 @@ loop closed under all of it:
   tracker's ``nan_guard`` instead of poisoning the bank; a dark
   sensor submits empty frames (tracks coast, then prune); duplicates
   and stale frames are dropped at admission by sequence number.
+* **Tracing** — the stages of a pump are ``jax.profiler``
+  ``TraceAnnotation`` spans (``katana.pump`` around ``katana.form``,
+  ``katana.dispatch``, ``katana.select``, ``katana.snapshot`` and
+  ``katana.checkpoint``), on the profiler's clock beside the device's
+  ops, and next to free while no profiler runs.
 
 ``serving/faults.py`` injects all of these faults deterministically;
 ``tests/test_chaos.py`` is the proof suite and ``benchmarks/serving.py``
@@ -59,24 +64,38 @@ import warnings
 from collections import deque
 from dataclasses import dataclass, field, replace
 from enum import Enum, IntEnum
-from typing import (Callable, Deque, Dict, List, Optional, Sequence,
-                    Tuple)
+from functools import wraps
+from typing import (Callable, Deque, Dict, List, NamedTuple, Optional,
+                    Sequence, Tuple)
 
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from repro.checkpoint.ckpt import CheckpointManager
 from repro.core import bank as bank_lib
 from repro.core.filters import IMMModel
 from repro.core.tracker import (FrameResult, TrackerConfig,
                                 make_multi_sensor_step)
-from repro.runtime.ft import HeartbeatMonitor, StragglerDetector
+from repro.runtime.ft import HeartbeatMonitor
 from repro.serving.engine import TrackSnapshot
 
 # Per-tenant track-id namespace stride: global id = ns_base + local id.
 # 2^20 local ids per tenant epoch is far beyond any bank capacity.
 NS_STRIDE = 1 << 20
+
+# Profiler spans of a pump, nested in PUMP_SPAN. FORM_SPAN: deadline
+# expiry, the plan, the batch and its host-to-device copies;
+# DISPATCH_SPAN: one shard's tracker step through block_until_ready;
+# SELECT_SPAN: the lane select; SNAPSHOT_SPAN: one update's
+# device-to-host copies; CHECKPOINT_SPAN: one lane's blocking save.
+PUMP_SPAN = "katana.pump"
+FORM_SPAN = "katana.form"
+DISPATCH_SPAN = "katana.dispatch"
+SELECT_SPAN = "katana.select"
+SNAPSHOT_SPAN = "katana.snapshot"
+CHECKPOINT_SPAN = "katana.checkpoint"
 
 
 class ServiceTier(IntEnum):
@@ -290,6 +309,8 @@ class StreamStats:
     coasted: int = 0            # empty frames applied (dark sensor)
     shed: int = 0               # frames applied coast-only by the ladder
     dispatches: int = 0         # fused step calls
+    lanes_dispatched: int = 0   # lanes that took part, over dispatches
+    step_traces: int = 0        # tier steps traced by this front end
     dispatch_errors: int = 0
     failovers: int = 0          # tenants migrated off dead shards
     shards_lost: int = 0
@@ -330,32 +351,49 @@ class _Shard:
     consecutive_failures: int = 0
 
 
+class _TierStep(NamedTuple):
+    one: object          # one empty single-sensor bank
+    axes: object         # the sensor-axis pytree
+    step: Callable       # jitted (banks, z, valid) -> FrameResult
+    model: object        # keeps id(model) in the cache key alive
+    traces: List[int]    # [times ``step`` was traced]
+
+
 # one jitted multi-sensor step per (model, cfg, lane count) — shared by
 # every shard and every front end so chaos tests don't recompile per
 # fleet (the step closure keeps ``model`` alive, so id() keys are
 # stable)
-_STEP_CACHE: Dict[Tuple, Tuple] = {}
+_STEP_CACHE: Dict[Tuple, _TierStep] = {}
 
 
-def _multi_step(model, cfg: TrackerConfig, lanes: int):
+def _multi_step(model, cfg: TrackerConfig, lanes: int) -> _TierStep:
     key = (id(model), cfg, lanes)
     if key not in _STEP_CACHE:
         one, axes, step = make_multi_sensor_step(model, cfg)
-        _STEP_CACHE[key] = (one, axes, jax.jit(step), model)
-    return _STEP_CACHE[key][:3]
+        traces = [0]
+
+        @wraps(step)
+        def counted(*args):
+            traces[0] += 1  # runs while jit traces, never per call
+            return step(*args)
+
+        _STEP_CACHE[key] = _TierStep(one, axes, jax.jit(counted), model,
+                                     traces)
+    return _STEP_CACHE[key]
 
 
 def _select_lanes(mask: np.ndarray, new, old, axes):
     """Per-lane select over a stacked bank: lane i takes ``new`` where
     mask[i], else keeps ``old`` — how idle tenants' lanes are frozen
     while the dispatch still runs as one fused call."""
-    m = jnp.asarray(mask)
+    with TraceAnnotation(SELECT_SPAN):
+        m = jnp.asarray(mask)
 
-    def sel(n, o, a):
-        shape = (1,) * a + (m.shape[0],) + (1,) * (n.ndim - a - 1)
-        return jnp.where(m.reshape(shape), n, o)
+        def sel(n, o, a):
+            shape = (1,) * a + (m.shape[0],) + (1,) * (n.ndim - a - 1)
+            return jnp.where(m.reshape(shape), n, o)
 
-    return jax.tree.map(sel, new, old, axes)
+        return jax.tree.map(sel, new, old, axes)
 
 
 class StreamFrontEnd:
@@ -372,8 +410,7 @@ class StreamFrontEnd:
 
     The ``clock`` is injectable (deadlines, heartbeats and the circuit
     breaker all read it) so every failure path is deterministic under
-    test; wall-time dispatch statistics always use
-    ``time.perf_counter``.
+    test.
     """
 
     def __init__(self, model, cfg: Optional[StreamConfig] = None,
@@ -405,7 +442,7 @@ class StreamFrontEnd:
                 * self.cfg.wide_gate_scale),
         }
         L = self.cfg.lanes_per_shard
-        one, axes, _ = _multi_step(model, self.tracker, L)
+        one, axes, *_ = _multi_step(model, self.tracker, L)
         self._one, self._axes = one, axes
         devs = list(devices) if devices is not None else jax.devices()
         self.shards: List[_Shard] = []
@@ -418,7 +455,6 @@ class StreamFrontEnd:
         self.monitor = HeartbeatMonitor([sh.name for sh in self.shards],
                                         self.cfg.heartbeat_timeout_s,
                                         clock)
-        self.stragglers = StragglerDetector([sh.name for sh in self.shards])
 
     # ------------------------------------------------------------ admission
     def attach(self, tenant: str) -> Admission:
@@ -528,63 +564,70 @@ class StreamFrontEnd:
         frame. Returns the applied updates keyed by tenant. Never
         raises on shard failure — errors feed the breaker and the
         failover path."""
-        now = self.clock()
-        # a reachable shard beats once per pump; a killed one goes
-        # silent and crosses the timeout after enough clock passes
-        for sh in self.shards:
-            if sh.alive and not sh.killed:
-                self.monitor.beat(sh.name)
-        self._recover_dead(now)
-        tier = self.effective_tier()
-        updates: Dict[str, TenantUpdate] = {}
-        for sh in self.shards:
-            if not sh.alive:
-                continue
-            self._pump_shard(sh, tier, now, updates)
-        return updates
+        with TraceAnnotation(PUMP_SPAN):
+            now = self.clock()
+            # a reachable shard beats once per pump; a killed one goes
+            # silent and crosses the timeout after enough clock passes
+            for sh in self.shards:
+                if sh.alive and not sh.killed:
+                    self.monitor.beat(sh.name)
+            self._recover_dead(now)
+            tier = self.effective_tier()
+            updates: Dict[str, TenantUpdate] = {}
+            for sh in self.shards:
+                if not sh.alive:
+                    continue
+                self._pump_shard(sh, tier, now, updates)
+            return updates
 
     def _pump_shard(self, sh: _Shard, tier: ServiceTier, now: float,
                     updates: Dict[str, TenantUpdate]) -> None:
         L, M, m = (self.cfg.lanes_per_shard, self.tracker.max_meas,
                    self.model.m)
-        zb = np.zeros((L, M, m), np.float32)
-        vb = np.zeros((L, M), bool)
-        participate = np.zeros((L,), bool)
-        plan: List[Tuple[_Tenant, FrameRequest, str]] = []
-        for name in self.alloc.tenants_on(sh.idx):
-            t = self.tenants[name]
-            while t.queue and t.queue[0].deadline is not None \
-                    and t.queue[0].deadline < now:
-                t.queue.popleft()
-                self.stats.expired += 1
-            if not t.queue:
-                continue  # lane frozen this pump
-            req = t.queue[0]  # peek — committed only if dispatch lands
-            k = min(len(req.z), M)
-            starving = t.sheds_in_row >= self.cfg.starve_limit - 1
-            if tier >= ServiceTier.COAST_ONLY and k and not starving:
-                kind = "shed"  # ladder sheds the measurements, keeps
-                # the cadence: the lane coasts via the valid mask
-            elif k == 0:
-                kind = "coast"
-            else:
-                # nominal service — or the anti-starvation floor firing
-                # under a coasting tier
-                kind = "served"
-                zb[t.lane, :k] = req.z[:k]
-                vb[t.lane, :k] = True
-            participate[t.lane] = True
-            plan.append((t, req, kind))
-        if sh.killed or not plan:
-            return  # dead: no result, queues intact; idle: lanes frozen
+        with TraceAnnotation(FORM_SPAN):
+            zb = np.zeros((L, M, m), np.float32)
+            vb = np.zeros((L, M), bool)
+            participate = np.zeros((L,), bool)
+            plan: List[Tuple[_Tenant, FrameRequest, str]] = []
+            for name in self.alloc.tenants_on(sh.idx):
+                t = self.tenants[name]
+                while t.queue and t.queue[0].deadline is not None \
+                        and t.queue[0].deadline < now:
+                    t.queue.popleft()
+                    self.stats.expired += 1
+                if not t.queue:
+                    continue  # lane frozen this pump
+                req = t.queue[0]  # peek — committed only if dispatch lands
+                k = min(len(req.z), M)
+                starving = t.sheds_in_row >= self.cfg.starve_limit - 1
+                if tier >= ServiceTier.COAST_ONLY and k and not starving:
+                    kind = "shed"  # ladder sheds the measurements, keeps
+                    # the cadence: the lane coasts via the valid mask
+                elif k == 0:
+                    kind = "coast"
+                else:
+                    # nominal service — or the anti-starvation floor
+                    # firing under a coasting tier
+                    kind = "served"
+                    zb[t.lane, :k] = req.z[:k]
+                    vb[t.lane, :k] = True
+                participate[t.lane] = True
+                plan.append((t, req, kind))
+            if sh.killed or not plan:
+                return  # dead: no result, queues intact; idle: frozen
+            z, valid = jnp.asarray(zb), jnp.asarray(vb)
         step_tier = (ServiceTier.WIDE_GATE if tier == ServiceTier.WIDE_GATE
                      else ServiceTier.FULL)
-        t0 = time.perf_counter()
-        try:
-            res = self._step_for(step_tier)(sh.banks, jnp.asarray(zb),
-                                            jnp.asarray(vb))
-            jax.block_until_ready(res.bank.x)
-        except Exception:  # noqa: BLE001 — the loop must keep closing
+        traces = _multi_step(self.model, self._tier_cfg[step_tier], L).traces
+        traced = traces[0]
+        with TraceAnnotation(DISPATCH_SPAN):
+            try:
+                res = self._step_for(step_tier)(sh.banks, z, valid)
+                jax.block_until_ready(res.bank.x)
+            except Exception:  # noqa: BLE001 — the loop must keep closing
+                res = None
+        self.stats.step_traces += traces[0] - traced
+        if res is None:
             self.stats.dispatch_errors += 1
             self.breaker.record_failure()
             sh.consecutive_failures += 1
@@ -592,11 +635,10 @@ class StreamFrontEnd:
                 sh.killed = True  # persistent failure == dead shard
                 sh.banks = None
             return
-        dt = time.perf_counter() - t0
         sh.consecutive_failures = 0
         self.breaker.record_success()
-        self.stragglers.record(sh.name, dt)
         self.stats.dispatches += 1
+        self.stats.lanes_dispatched += len(plan)
         sh.banks = _select_lanes(participate, res.bank, sh.banks,
                                  self._axes)
         counters = {"served": "served", "coast": "coasted", "shed": "shed"}
@@ -621,50 +663,51 @@ class StreamFrontEnd:
 
     def _step_for(self, tier: ServiceTier):
         cfg = self._tier_cfg[tier]
-        _, _, step = _multi_step(self.model, cfg,
-                                 self.cfg.lanes_per_shard)
-        return step
+        return _multi_step(self.model, cfg, self.cfg.lanes_per_shard).step
 
     def _lane_snapshots(self, res: FrameResult, lane: int,
                         ns_base: int) -> List[TrackSnapshot]:
-        conf = np.asarray(res.confirmed)[lane]
-        idx = np.nonzero(conf)[0]
-        if not len(idx):
-            return []
-        bank = res.bank
-        ids = np.asarray(bank.track_id)[lane]
-        hits = np.asarray(bank.hits)[lane]
-        age = np.asarray(bank.age)[lane]
-        if self.is_imm:
-            xs = np.asarray(res.x_est)[lane]
-            mus = np.asarray(res.mode_probs)[lane]
-        else:
-            xs, mus = np.asarray(bank.x)[lane], None
-        return [TrackSnapshot(ns_base + int(ids[i]), xs[i].copy(),
-                              int(hits[i]), int(age[i]),
-                              mus[i].copy() if mus is not None else None)
-                for i in idx]
+        with TraceAnnotation(SNAPSHOT_SPAN):
+            conf = np.asarray(res.confirmed)[lane]
+            idx = np.nonzero(conf)[0]
+            if not len(idx):
+                return []
+            bank = res.bank
+            ids = np.asarray(bank.track_id)[lane]
+            hits = np.asarray(bank.hits)[lane]
+            age = np.asarray(bank.age)[lane]
+            if self.is_imm:
+                xs = np.asarray(res.x_est)[lane]
+                mus = np.asarray(res.mode_probs)[lane]
+            else:
+                xs, mus = np.asarray(bank.x)[lane], None
+            return [TrackSnapshot(ns_base + int(ids[i]), xs[i].copy(),
+                                  int(hits[i]), int(age[i]),
+                                  mus[i].copy() if mus is not None else None)
+                    for i in idx]
 
     # ----------------------------------------------------------- checkpoint
     def _checkpoint(self, t: _Tenant) -> None:
-        sh = self.shards[t.shard]
-        lane_bank = bank_lib.slice_sensor_bank(sh.banks, t.lane)
-        try:
-            t.ckpt.save(t.frames_applied, lane_bank,
-                        extra=dict(tenant=t.name, frame=t.frames_applied,
-                                   ns_base=t.ns_base,
-                                   next_seq=t.next_seq),
-                        blocking=True)
-        except OSError as e:
-            # keep the WAL — failover replays from the older snapshot
-            warnings.warn(f"checkpoint for tenant {t.name!r} at frame "
-                          f"{t.frames_applied} failed ({e!r}); WAL "
-                          f"retained back to frame {t.ckpt_frame}",
-                          RuntimeWarning, stacklevel=2)
-            return
-        t.ckpt_frame = t.frames_applied
-        t.wal.clear()
-        self.stats.checkpoints += 1
+        with TraceAnnotation(CHECKPOINT_SPAN):
+            sh = self.shards[t.shard]
+            lane_bank = bank_lib.slice_sensor_bank(sh.banks, t.lane)
+            try:
+                t.ckpt.save(t.frames_applied, lane_bank,
+                            extra=dict(tenant=t.name,
+                                       frame=t.frames_applied,
+                                       ns_base=t.ns_base,
+                                       next_seq=t.next_seq),
+                            blocking=True)
+            except OSError as e:
+                # keep the WAL — failover replays from the older snapshot
+                warnings.warn(f"checkpoint for tenant {t.name!r} at frame "
+                              f"{t.frames_applied} failed ({e!r}); WAL "
+                              f"retained back to frame {t.ckpt_frame}",
+                              RuntimeWarning, stacklevel=2)
+                return
+            t.ckpt_frame = t.frames_applied
+            t.wal.clear()
+            self.stats.checkpoints += 1
 
     # ------------------------------------------------------------- failover
     def _recover_dead(self, now: float) -> None:
@@ -686,7 +729,6 @@ class StreamFrontEnd:
             self.alloc.release(name)
         self.alloc.drop_shard(sh.idx)
         self.monitor.remove(sh.name)
-        self.stragglers.remove(sh.name)
         sh.banks = None
         for name in moved:
             t = self.tenants[name]
