@@ -311,6 +311,7 @@ class StreamStats:
     dispatches: int = 0         # fused step calls
     lanes_dispatched: int = 0   # lanes that took part, over dispatches
     step_traces: int = 0        # tier steps traced by this front end
+    select_traces: int = 0      # lane selects traced by this front end
     dispatch_errors: int = 0
     failovers: int = 0          # tenants migrated off dead shards
     shards_lost: int = 0
@@ -382,18 +383,42 @@ def _multi_step(model, cfg: TrackerConfig, lanes: int) -> _TierStep:
     return _STEP_CACHE[key]
 
 
+class _LaneSelect(NamedTuple):
+    select: Callable     # jitted (mask, new, old) -> merged stacked bank
+    traces: List[int]    # [times ``select`` was traced]
+
+
+# one jitted lane select per sensor-axis pytree (BankState and
+# IMMBankState stacks differ), shared by every front end; the mask is
+# a traced argument, so every lane pattern runs the same program
+_SELECT_CACHE: Dict[Tuple, _LaneSelect] = {}
+
+
+def _lane_select(axes) -> _LaneSelect:
+    key = (type(axes), axes)
+    if key not in _SELECT_CACHE:
+        traces = [0]
+
+        def select(mask, new, old):
+            traces[0] += 1  # runs while jit traces, never per call
+
+            def sel(n, o, a):
+                shape = (1,) * a + (mask.shape[0],) + (1,) * (n.ndim - a - 1)
+                return jnp.where(mask.reshape(shape), n, o)
+
+            return jax.tree.map(sel, new, old, axes)
+
+        _SELECT_CACHE[key] = _LaneSelect(jax.jit(select), traces)
+    return _SELECT_CACHE[key]
+
+
 def _select_lanes(mask: np.ndarray, new, old, axes):
     """Per-lane select over a stacked bank: lane i takes ``new`` where
     mask[i], else keeps ``old`` — how idle tenants' lanes are frozen
-    while the dispatch still runs as one fused call."""
+    while the dispatch still runs as one fused call. One compiled
+    program per ``axes`` pytree does the whole merge."""
     with TraceAnnotation(SELECT_SPAN):
-        m = jnp.asarray(mask)
-
-        def sel(n, o, a):
-            shape = (1,) * a + (m.shape[0],) + (1,) * (n.ndim - a - 1)
-            return jnp.where(m.reshape(shape), n, o)
-
-        return jax.tree.map(sel, new, old, axes)
+        return _lane_select(axes).select(mask, new, old)
 
 
 class StreamFrontEnd:
@@ -639,8 +664,11 @@ class StreamFrontEnd:
         self.breaker.record_success()
         self.stats.dispatches += 1
         self.stats.lanes_dispatched += len(plan)
+        select_traces = _lane_select(self._axes).traces
+        traced = select_traces[0]
         sh.banks = _select_lanes(participate, res.bank, sh.banks,
                                  self._axes)
+        self.stats.select_traces += select_traces[0] - traced
         counters = {"served": "served", "coast": "coasted", "shed": "shed"}
         for t, req, kind in plan:
             t.queue.popleft()  # commit
