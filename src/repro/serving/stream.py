@@ -317,6 +317,7 @@ class StreamStats:
     shards_lost: int = 0
     checkpoints: int = 0
     parked: int = 0             # tenants with no surviving lane
+    fanout_pumps: int = 0       # pumps that called more than one shard's step
 
     @property
     def applied(self) -> int:
@@ -599,14 +600,16 @@ class StreamFrontEnd:
             self._recover_dead(now)
             tier = self.effective_tier()
             updates: Dict[str, TenantUpdate] = {}
-            for sh in self.shards:
-                if not sh.alive:
-                    continue
-                self._pump_shard(sh, tier, now, updates)
+            launched = sum(self._pump_shard(sh, tier, now, updates)
+                           for sh in self.shards if sh.alive)
+            if launched > 1:
+                self.stats.fanout_pumps += 1
             return updates
 
     def _pump_shard(self, sh: _Shard, tier: ServiceTier, now: float,
-                    updates: Dict[str, TenantUpdate]) -> None:
+                    updates: Dict[str, TenantUpdate]) -> bool:
+        """Serve one shard's pending tenants; True if its tier step was
+        called (a failed call included)."""
         L, M, m = (self.cfg.lanes_per_shard, self.tracker.max_meas,
                    self.model.m)
         with TraceAnnotation(FORM_SPAN):
@@ -639,8 +642,11 @@ class StreamFrontEnd:
                 participate[t.lane] = True
                 plan.append((t, req, kind))
             if sh.killed or not plan:
-                return  # dead: no result, queues intact; idle: frozen
-            z, valid = jnp.asarray(zb), jnp.asarray(vb)
+                return False  # dead: no result, queues intact; idle: frozen
+            # straight from the host to the shard's chip, uncommitted like
+            # the warm-up's batch, so the step's compiled signature holds
+            with jax.default_device(sh.device):
+                z, valid = jnp.asarray(zb), jnp.asarray(vb)
         step_tier = (ServiceTier.WIDE_GATE if tier == ServiceTier.WIDE_GATE
                      else ServiceTier.FULL)
         traces = _multi_step(self.model, self._tier_cfg[step_tier], L).traces
@@ -659,7 +665,7 @@ class StreamFrontEnd:
             if sh.consecutive_failures >= self.cfg.breaker_failures:
                 sh.killed = True  # persistent failure == dead shard
                 sh.banks = None
-            return
+            return True
         sh.consecutive_failures = 0
         self.breaker.record_success()
         self.stats.dispatches += 1
@@ -688,6 +694,7 @@ class StreamFrontEnd:
                 self._lane_snapshots(res, t.lane, t.ns_base))
             if t.frames_applied - t.ckpt_frame >= self.cfg.checkpoint_every:
                 self._checkpoint(t)
+        return True
 
     def _step_for(self, tier: ServiceTier):
         cfg = self._tier_cfg[tier]
