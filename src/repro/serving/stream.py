@@ -318,6 +318,7 @@ class StreamStats:
     checkpoints: int = 0
     parked: int = 0             # tenants with no surviving lane
     fanout_pumps: int = 0       # pumps that called more than one shard's step
+    snapshot_prefetches: int = 0  # step outputs copied before the snapshots
 
     @property
     def applied(self) -> int:
@@ -411,6 +412,16 @@ def _lane_select(axes) -> _LaneSelect:
 
         _SELECT_CACHE[key] = _LaneSelect(jax.jit(select), traces)
     return _SELECT_CACHE[key]
+
+
+def _snapshot_fields(res: FrameResult, is_imm: bool) -> tuple:
+    """The step outputs a lane's snapshots read, in order: confirmed,
+    track ids, hits, ages, states, and (IMM) mode probabilities."""
+    bank = res.bank
+    if is_imm:
+        return (res.confirmed, bank.track_id, bank.hits, bank.age,
+                res.x_est, res.mode_probs)
+    return res.confirmed, bank.track_id, bank.hits, bank.age, bank.x
 
 
 def _select_lanes(mask: np.ndarray, new, old, axes):
@@ -670,6 +681,12 @@ class StreamFrontEnd:
         self.breaker.record_success()
         self.stats.dispatches += 1
         self.stats.lanes_dispatched += len(plan)
+        # start every snapshot copy now, so the round trips overlap each
+        # other and the select instead of running one after another
+        fields = _snapshot_fields(res, self.is_imm)
+        for a in fields:
+            a.copy_to_host_async()
+        self.stats.snapshot_prefetches += len(fields)
         select_traces = _lane_select(self._axes).traces
         traced = select_traces[0]
         sh.banks = _select_lanes(participate, res.bank, sh.banks,
@@ -703,23 +720,13 @@ class StreamFrontEnd:
     def _lane_snapshots(self, res: FrameResult, lane: int,
                         ns_base: int) -> List[TrackSnapshot]:
         with TraceAnnotation(SNAPSHOT_SPAN):
-            conf = np.asarray(res.confirmed)[lane]
-            idx = np.nonzero(conf)[0]
-            if not len(idx):
-                return []
-            bank = res.bank
-            ids = np.asarray(bank.track_id)[lane]
-            hits = np.asarray(bank.hits)[lane]
-            age = np.asarray(bank.age)[lane]
-            if self.is_imm:
-                xs = np.asarray(res.x_est)[lane]
-                mus = np.asarray(res.mode_probs)[lane]
-            else:
-                xs, mus = np.asarray(bank.x)[lane], None
+            fields = _snapshot_fields(res, self.is_imm)
+            conf, ids, hits, age, xs, *mus = (np.asarray(a)[lane]
+                                              for a in fields)
             return [TrackSnapshot(ns_base + int(ids[i]), xs[i].copy(),
                                   int(hits[i]), int(age[i]),
-                                  mus[i].copy() if mus is not None else None)
-                    for i in idx]
+                                  mus[0][i].copy() if mus else None)
+                    for i in np.nonzero(conf)[0]]
 
     # ----------------------------------------------------------- checkpoint
     def _checkpoint(self, t: _Tenant) -> None:

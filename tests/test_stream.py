@@ -13,8 +13,11 @@ without hypothesis installed):
 Unit layer: admission decisions (duplicates, drop-oldest, queue-full,
 overload reject, deadline expiry), the circuit breaker state machine,
 cross-tenant isolation of the fused dispatch, idle-lane freezing, the
-NaN guard coasting corrupt payloads, and checkpoint cadence.
+NaN guard coasting corrupt payloads, checkpoint cadence, and the
+snapshot prefetch (bitwise snapshots, its counter, nothing on a failed
+dispatch).
 """
+import jax
 import numpy as np
 import pytest
 
@@ -344,3 +347,97 @@ def test_wide_gate_tier_uses_scaled_config(tmp_path):
         TRACKER.gate_scale * fe.cfg.wide_gate_scale)
     # the base config is untouched — tiers are separate static configs
     assert fe._tier_cfg[ServiceTier.FULL].gate_scale == TRACKER.gate_scale
+
+
+# ----------------------------------------------------- snapshot prefetch
+def steady(frame):
+    """Two targets moving steadily: tracks confirm after a few frames."""
+    return np.float32([[1.0 + 0.1 * frame, 2.0, 0.0],
+                       [-3.0, 0.5 - 0.1 * frame, 1.0]])
+
+
+def capture_steps(fe):
+    """Wrap the front end's tier steps so every FrameResult they return
+    is kept, in dispatch order."""
+    results, step_for = [], fe._step_for
+
+    def wrapped(tier):
+        step = step_for(tier)
+
+        def run(*args):
+            results.append(step(*args))
+            return results[-1]
+        return run
+
+    fe._step_for = wrapped
+    return results
+
+
+@pytest.mark.parametrize("model", [CV, MODEL], ids=["lkf", "imm"])
+def test_prefetched_snapshots_bitwise_equal_step_outputs(tmp_path, model):
+    """The snapshots a pump returns hold bitwise the step's own outputs,
+    read straight off the FrameResult, for every confirmed track."""
+    fe = StreamFrontEnd(model, StreamConfig(n_shards=1, lanes_per_shard=2),
+                        TRACKER, ckpt_dir=str(tmp_path), clock=FakeClock())
+    fe.attach("a")
+    fe.attach("b")
+    results = capture_steps(fe)
+    imm = model is MODEL
+    for f in range(6):
+        fe.submit("a", steady(f))
+        fe.submit("b", steady(f) + 1.0)
+        ups = fe.pump()
+        host = jax.device_get(results[-1])
+        xs = host.x_est if imm else host.bank.x
+        for name in ("a", "b"):
+            t = fe.tenants[name]
+            idx = np.nonzero(host.confirmed[t.lane])[0]
+            snaps = ups[name].snapshots
+            assert [s.track_id for s in snaps] == \
+                [t.ns_base + int(i) for i in host.bank.track_id[t.lane][idx]]
+            for s, i in zip(snaps, idx):
+                np.testing.assert_array_equal(s.state, xs[t.lane][i])
+                assert s.hits == host.bank.hits[t.lane][i]
+                assert s.age == host.bank.age[t.lane][i]
+                if imm:
+                    np.testing.assert_array_equal(
+                        s.mode_probs, host.mode_probs[t.lane][i])
+                else:
+                    assert s.mode_probs is None
+    assert ups["a"].snapshots and ups["b"].snapshots
+
+
+@pytest.mark.parametrize("model,fields", [(CV, 5), (MODEL, 6)],
+                         ids=["lkf", "imm"])
+def test_snapshot_prefetches_count_fields_per_dispatch(tmp_path, model,
+                                                       fields):
+    fe = StreamFrontEnd(model, StreamConfig(n_shards=2, lanes_per_shard=2),
+                        TRACKER, ckpt_dir=str(tmp_path), clock=FakeClock())
+    for name in ("a", "b", "c"):
+        fe.attach(name)
+    for f in range(4):
+        for name in ("a", "b", "c")[:1 + f % 3]:
+            fe.submit(name, scene(f))
+        fe.pump()
+    assert fe.stats.dispatches > 4  # some pumps fanned out to two shards
+    assert fe.stats.snapshot_prefetches == fields * fe.stats.dispatches
+
+
+def test_failed_dispatch_prefetches_nothing(tmp_path):
+    fe = make_front(tmp_path, n_shards=1)
+    fe.attach("a")
+    fe.submit("a", scene(0))
+    fe.pump()
+    before = fe.stats.snapshot_prefetches
+    assert before == 6
+
+    def failing(tier):
+        def run(*args):
+            raise RuntimeError("injected dispatch failure")
+        return run
+
+    fe._step_for = failing
+    fe.submit("a", scene(1))
+    assert fe.pump() == {}
+    assert fe.stats.dispatch_errors == 1
+    assert fe.stats.snapshot_prefetches == before
