@@ -12,12 +12,7 @@ The backend decides how a kernel runs, with no probe and no fallback:
     CPU, it raises.
 
 Nothing else chooses the mode: no env var, no config field. The
-resolved ``ExecMode`` names the backend and jax version so every
-BENCH_*.json row records how its code actually executed:
-``lowering="pallas"`` (natively compiled kernel),
-``"pallas-interpret"`` (kernel through the interpreter), or ``"xla"``
-(the XLA-native einsum/lanes formulation — compiled code on every
-backend, including CPU).
+resolved ``ExecMode`` names the mode, the backend and the jax version.
 """
 from __future__ import annotations
 
@@ -35,26 +30,6 @@ class ExecMode:
     def interpret(self) -> bool:
         """What the kernel ops pass to ``pallas_call``."""
         return self.mode == "interpret"
-
-    def lowering(self, pallas: bool = True) -> str:
-        """How a code path executes under this mode: ``"xla"`` for the
-        einsum/lanes formulations (native compiled code everywhere),
-        ``"pallas"`` / ``"pallas-interpret"`` for kernel dispatches."""
-        if not pallas:
-            return "xla"
-        return "pallas" if self.mode == "compiled" else "pallas-interpret"
-
-    def row_mode(self, pallas: bool = True) -> str:
-        """The honest per-BENCH-row mode label: XLA-native paths are
-        compiled code on every backend; Pallas paths are compiled only
-        on a TPU backend."""
-        return "interpret" if self.lowering(pallas) == "pallas-interpret" \
-            else "compiled"
-
-    def as_meta(self) -> dict:
-        """Top-of-file metadata for BENCH_*.json."""
-        return dict(mode=self.mode, backend=self.backend,
-                    jax=self.jax_version)
 
 
 def backend_mode(backend: str) -> str:

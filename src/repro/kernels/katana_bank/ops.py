@@ -40,12 +40,9 @@ Execution mode: every op's ``interpret`` parameter defaults to ``None``
 probe and no fallback — a kernel the TPU compiler refuses raises — and
 the Pallas interpreter on CPU. ``interpret=True`` pins the interpreter
 (CPU only: the kernel equivalence tests do), ``interpret=False`` pins
-compilation (the compile rehearsals for a described TPU do). Likewise
-``lane_tile``/``time_chunk`` default to 0 = "consult the autotuned
-table" (``autotune.tuned.json``, keyed on kernel x bank size x backend
-x mode), falling back to the static defaults when no measurement
-matches. The raw ``kernel.py`` step functions below this layer take
-``interpret`` as a required keyword; ops is where policy is resolved.
+compilation (the compile rehearsals for a described TPU do). The raw
+``kernel.py`` step functions below this layer take ``interpret`` as a
+required keyword; ops is where policy is resolved.
 """
 from __future__ import annotations
 
@@ -59,8 +56,6 @@ import numpy as np
 from repro.core.filters import FilterModel, IMMModel
 from repro.core.rewrites import imm_combine, imm_mix, imm_mode_posterior
 from repro.execmode import resolve_interpret
-from repro.kernels.katana_bank.autotune import (tuned_lane_tile,
-                                               tuned_time_chunk)
 from repro.kernels.katana_bank.kernel import (
     LANE_TILE,
     _selector_rows,
@@ -91,18 +86,15 @@ def _pad_to(x, N_pad, axis=-1):
     return jnp.pad(x, widths)
 
 
-def katana_bank(model: FilterModel, x, P, z, lane_tile: int = 0,
+def katana_bank(model: FilterModel, x, P, z, lane_tile: int = LANE_TILE,
                 symmetrize: bool = True,
                 interpret: Optional[bool] = None):
     """Fused batched KF step.
 
     x: (N, n); P: (N, n, n); z: (N, m)  ->  (x', P') same shapes.
-    ``lane_tile=0`` consults the autotuned table; ``interpret=None``
-    resolves from the active execution mode.
+    ``interpret=None`` resolves from the active execution mode.
     """
     interpret = resolve_interpret(interpret)
-    lane_tile = lane_tile or tuned_lane_tile("katana_bank", x.shape[0],
-                                             LANE_TILE)
     return _katana_bank(model, x, P, z, lane_tile=lane_tile,
                         symmetrize=symmetrize, interpret=interpret)
 
@@ -125,7 +117,7 @@ def _katana_bank(model: FilterModel, x, P, z, lane_tile: int,
 
 
 def katana_bank_sequence(model: FilterModel, zs, x0, P0,
-                         lane_tile: int = 0,
+                         lane_tile: int = LANE_TILE,
                          symmetrize: bool = True,
                          interpret: Optional[bool] = None,
                          return_final: bool = False,
@@ -143,18 +135,12 @@ def katana_bank_sequence(model: FilterModel, zs, x0, P0,
     in VMEM, so streams longer than ``time_chunk`` frames run as
     ceil(T / time_chunk) dispatches with (x, P) carried between them —
     the bank still only round-trips HBM once per CHUNK, not per frame.
-    ``lane_tile=0`` / ``time_chunk=0`` consult the autotuned table
-    (static fallbacks LANE_TILE / the VMEM-fitting
-    ``kernel.scan_time_chunk``); ``interpret=None`` resolves from the
-    active execution mode.
+    ``time_chunk=0`` takes the longest chunk whose whole-T blocks fit
+    the scoped VMEM (``kernel.scan_time_chunk``); ``interpret=None``
+    resolves from the active execution mode.
     """
-    N = jnp.shape(zs)[1]
     interpret = resolve_interpret(interpret)
-    lane_tile = lane_tile or tuned_lane_tile("katana_bank_sequence", N,
-                                             LANE_TILE)
-    time_chunk = time_chunk or tuned_time_chunk(
-        "katana_bank_sequence", N, scan_time_chunk(model.n, model.m,
-                                                   lane_tile))
+    time_chunk = time_chunk or scan_time_chunk(model.n, model.m, lane_tile)
     return _katana_bank_sequence(model, zs, x0, P0, lane_tile=lane_tile,
                                  symmetrize=symmetrize, interpret=interpret,
                                  return_final=return_final,
@@ -331,7 +317,7 @@ def _imm_lane_table(imm: IMMModel, N: int, L_pad: int,
     return (V @ sel).astype(dtype)
 
 
-def katana_bank_imm(imm: IMMModel, x, P, z, lane_tile: int = 0,
+def katana_bank_imm(imm: IMMModel, x, P, z, lane_tile: int = LANE_TILE,
                     symmetrize: bool = True,
                     interpret: Optional[bool] = None):
     """Fused multi-model (IMM) KF step + measurement log-likelihoods.
@@ -347,8 +333,6 @@ def katana_bank_imm(imm: IMMModel, x, P, z, lane_tile: int = 0,
     batching argument applied to the model index).
     """
     interpret = resolve_interpret(interpret)
-    lane_tile = lane_tile or tuned_lane_tile(
-        "katana_bank_imm", x.shape[0] * x.shape[1], LANE_TILE)
     return _katana_bank_imm(imm, x, P, z, lane_tile=lane_tile,
                             symmetrize=symmetrize, interpret=interpret)
 
@@ -388,7 +372,7 @@ def katana_imm_sequence(imm: IMMModel, zs, x0, P0, mu0=None, valid=None,
                         lane_tile: int = 0, symmetrize: bool = True,
                         interpret: Optional[bool] = None,
                         return_final: bool = False,
-                        time_chunk: int = 0):
+                        time_chunk: int = 64):
     """Fused IMM filtering of a (T, N, m) measurement stream: ONE kernel
     dispatch per time chunk (the ``imm_scan`` stage fast path).
 
@@ -404,10 +388,9 @@ def katana_imm_sequence(imm: IMMModel, zs, x0, P0, mu0=None, valid=None,
 
     ``lane_tile`` here counts TRACKS per program (each program holds all
     K model slabs of its tracks, K·lane_tile lanes); the default 0
-    first consults the autotuned table, then falls back to
-    ``imm_track_tile`` (256 at K=1, 128 for K>1). The ``time_chunk``
-    fallback (64) bounds the per-program VMEM instead: at K=4, cv-9 and
-    128 tracks the streamed zs/xs/valid blocks take
+    takes ``imm_track_tile`` (256 at K=1, 128 for K>1). The
+    ``time_chunk`` default (64) bounds the per-program VMEM instead: at
+    K=4, cv-9 and 128 tracks the streamed zs/xs/valid blocks take
     64 · (8 + 16 + 8) rows · 128 · 4 B · 2 buffers = 2 MiB, the x/P/mu
     state blocks about 1.3 MiB, well inside the 16 MiB scoped VMEM.
 
@@ -418,11 +401,8 @@ def katana_imm_sequence(imm: IMMModel, zs, x0, P0, mu0=None, valid=None,
     a whole chunk, and the lane padding + AoS->SoA transposes are paid
     once per sequence. K=1 reduces exactly to ``katana_bank_sequence``.
     """
-    N = jnp.shape(zs)[1]
     interpret = resolve_interpret(interpret)
-    lane_tile = lane_tile or tuned_lane_tile("katana_imm_sequence", N,
-                                             imm_track_tile(imm.K))
-    time_chunk = time_chunk or tuned_time_chunk("katana_imm_sequence", N, 64)
+    lane_tile = lane_tile or imm_track_tile(imm.K)
     return _katana_imm_sequence(imm, zs, x0, P0, mu0, valid,
                                 lane_tile=lane_tile, symmetrize=symmetrize,
                                 interpret=interpret,
@@ -483,7 +463,7 @@ def _katana_imm_sequence(imm: IMMModel, zs, x0, P0, mu0, valid,
 
 
 def imm_bank_sequence(imm: IMMModel, zs, x0, P0, mu0=None,
-                      lane_tile: int = 0, symmetrize: bool = True,
+                      lane_tile: int = LANE_TILE, symmetrize: bool = True,
                       interpret: Optional[bool] = None,
                       return_final: bool = False):
     """IMM-filter a (T, N, m) measurement stream: one jitted lax.scan,
@@ -503,8 +483,6 @@ def imm_bank_sequence(imm: IMMModel, zs, x0, P0, mu0=None,
     driver remains as its independently-built equivalence oracle.
     """
     interpret = resolve_interpret(interpret)
-    lane_tile = lane_tile or tuned_lane_tile(
-        "imm_bank_sequence", imm.K * jnp.shape(zs)[1], LANE_TILE)
     return _imm_bank_sequence(imm, zs, x0, P0, mu0, lane_tile=lane_tile,
                               symmetrize=symmetrize, interpret=interpret,
                               return_final=return_final)

@@ -10,8 +10,7 @@ For every (arch x shape x mesh) cell:
   2. COST PROBES (unrolled, depth p and 2p, microbatches=1): FLOPs /
      bytes / collective wire-bytes extrapolated to full depth
      (cost_analysis counts scan bodies once — DESIGN.md §4).
-Artifacts land in results/dryrun/<mesh>/<arch>/<shape>.json and are
-consumed by benchmarks/roofline.py.
+Artifacts land in results/dryrun/<mesh>/<arch>/<shape>.json.
 
 Usage:
   python -m repro.launch.dryrun --arch qwen3-moe-235b-a22b \

@@ -1,11 +1,7 @@
 """Three-term roofline: machine peaks + the compute/memory/collective
 time terms.
 
-Originally built for the dry-run artifacts (TPU v5e targets); now also
-the model behind ``benchmarks/roofline.py``'s katana-kernel rows, which
-compare ``cost_analysis()``-measured FLOPs/bytes of the compiled
-programs against the analytic useful-work floor on a per-backend
-``Machine``.
+Built for the dry-run artifacts (TPU v5e targets).
 
 Methodology (DESIGN.md §4, calibrated on this container):
   * ``cost_analysis()`` is per-device, post-SPMD.

@@ -52,9 +52,7 @@ loop closed under all of it:
   ops, and next to free while no profiler runs.
 
 ``serving/faults.py`` injects all of these faults deterministically;
-``tests/test_chaos.py`` is the proof suite and ``benchmarks/serving.py``
-measures sustained FPS vs offered load and recovery time after a
-shard kill (``BENCH_serving.json``).
+``tests/test_chaos.py`` is the proof suite.
 """
 from __future__ import annotations
 

@@ -36,7 +36,7 @@ def test_docs_python_block_runs(path, code):
     src = str(ROOT / "src")
     if src not in sys.path:
         sys.path.insert(0, src)
-    # run from the repo root so relative paths (BENCH_*.json) resolve
+    # run from the repo root so relative paths (BENCHMARK.json) resolve
     import os
 
     cwd = os.getcwd()

@@ -1,18 +1,16 @@
-"""Execution-mode resolution: the backend decides, no fallback, row labels.
+"""Execution-mode resolution: the backend decides, no fallback.
 
 The contract under test (src/repro/execmode.py): the backend alone
 decides interpret-vs-compiled for every kernel op — compiled on TPU,
 the Pallas interpreter elsewhere — with no capability probe and no
 fallback: a kernel that cannot lower raises, and the interpreter never
-runs on a TPU backend. Per-BENCH-row labels call XLA-native paths
-compiled everywhere but Pallas paths compiled only on TPU.
+runs on a TPU backend.
 """
 import jax
 import numpy as np
 import pytest
 
-from repro.execmode import (ExecMode, active_mode, backend_mode,
-                            resolve_interpret)
+from repro.execmode import active_mode, backend_mode, resolve_interpret
 
 
 def _lkf_bank(N=4):
@@ -96,26 +94,6 @@ def test_explicit_interpret_beats_mode():
         assert resolve_interpret(True) is True
     assert resolve_interpret(False) is False
     assert resolve_interpret(None) is active_mode().interpret
-
-
-def test_row_labels_are_honest():
-    """XLA rows are compiled everywhere; Pallas rows are compiled only
-    on a TPU backend."""
-    native = ExecMode("compiled", "tpu", "x")
-    interp = ExecMode("interpret", "cpu", "x")
-    assert native.lowering(pallas=True) == "pallas"
-    assert native.row_mode(pallas=True) == "compiled"
-    assert interp.lowering(pallas=True) == "pallas-interpret"
-    assert interp.row_mode(pallas=True) == "interpret"
-    for m in (native, interp):
-        assert m.lowering(pallas=False) == "xla"
-        assert m.row_mode(pallas=False) == "compiled"
-
-
-def test_as_meta_round_trips_the_facts():
-    m = active_mode()
-    meta = m.as_meta()
-    assert meta == dict(mode=m.mode, backend=m.backend, jax=jax.__version__)
 
 
 def test_ops_honor_resolved_mode():

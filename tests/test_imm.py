@@ -171,8 +171,7 @@ def test_small_det_matches_numpy():
 # ------------------------------------------------------------ accuracy win
 def test_imm_beats_single_cv_on_maneuvering_scene():
     """The headline claim: on the CV/CT/CA switching scene the IMM bank
-    has materially lower position RMSE than the single-model CV LKF
-    (same claim benchmarks/imm.py records into BENCH_imm.json)."""
+    has materially lower position RMSE than the single-model CV LKF."""
     T, N = 96, 8
     truth, zs = maneuvering_batch(T, N, seed=1)
     cv = get_filter("lkf")
